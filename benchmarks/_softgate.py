@@ -31,6 +31,18 @@ import sys
 SLOWDOWN_WARN_FRACTION = 0.30
 
 
+def cold_compile_metric(cold_s: float, cache_hits: int) -> dict:
+    """The first-call seconds, keyed by whether that call really compiled.
+
+    With the persistent compile cache on (``repro.launch.cache``), a rerun
+    in the same checkout loads the family from disk instead of compiling
+    it.  Such a time goes under ``cold_cached_s``, so the ``cold_s`` trend
+    in ``BENCH_history.jsonl`` only ever compares real compiles.
+    """
+    key = "cold_s" if cache_hits == 0 else "cold_cached_s"
+    return {key: cold_s, "cold_cache_hits": int(cache_hits)}
+
+
 def committed_baseline_with_source(path: str) -> tuple[dict, str]:
     """The committed manifest at ``path`` plus WHERE it came from.
 

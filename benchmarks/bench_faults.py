@@ -30,7 +30,8 @@ from __future__ import annotations
 import os
 import time
 
-from benchmarks._softgate import (collect, committed_baseline, warn_compiles,
+from benchmarks._softgate import (cold_compile_metric, collect,
+                                  committed_baseline, warn_compiles,
                                   warn_slowdown)
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
@@ -60,6 +61,7 @@ def run() -> list[dict]:
 
     from repro import faults, sweeps
     from repro.core.lea import PoolLoad
+    from repro.obs import counters
     from repro.runtime.fault_tolerance import (CodedDataParallelExecutor,
                                                CodedDPConfig, OUTCOMES)
 
@@ -92,6 +94,7 @@ def run() -> list[dict]:
     ])
 
     c0 = faults.fault_compile_cache_size()
+    h0 = counters.persistent_cache_hits()
     t0 = time.perf_counter()
     out = faults.sweep_faults(
         keys, pool, p_gg, p_bb,
@@ -101,6 +104,7 @@ def run() -> list[dict]:
     )
     jax.block_until_ready(out)
     cold_s = time.perf_counter() - t0
+    cold_hits = counters.persistent_cache_hits() - h0
     compiles = faults.fault_compile_cache_size() - c0
     # the whole fault grid — every (p_preempt, p_drop) cell — is ONE compile
     assert compiles == 1, compiles
@@ -186,7 +190,7 @@ def run() -> list[dict]:
         "rows_per_sec": rows_per_sec,
         "baseline_rows_per_sec": baseline.get("rows_per_sec"),
         "slowdown_warned": slowdown_warned,
-        "cold_s": cold_s,
+        **cold_compile_metric(cold_s, cold_hits),
         "warm_s": warm_s,
         "executor_rounds": ex.rounds,
         "executor_outcomes": {k: ex.outcomes[k] for k in OUTCOMES},

@@ -35,7 +35,8 @@ from __future__ import annotations
 import os
 import time
 
-from benchmarks._softgate import (collect, committed_baseline, warn_compiles,
+from benchmarks._softgate import (cold_compile_metric, collect,
+                                  committed_baseline, warn_compiles,
                                   warn_slowdown)
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
@@ -66,6 +67,7 @@ def run() -> list[dict]:
 
     from repro import serving, sweeps
     from repro.core import markov
+    from repro.obs import counters
 
     scenarios = sweeps.expand(FAMILY, rounds=ROUNDS)
     b = len(scenarios)
@@ -106,6 +108,7 @@ def run() -> list[dict]:
     zeros = jnp.zeros((b,), jnp.float32)
 
     c0 = serving.serving_compile_cache_size()
+    h0 = counters.persistent_cache_hits()
     t0 = time.perf_counter()
     out_all = serving.sweep_serving(
         keys, pool_mask, p_gg, p_bb,
@@ -115,6 +118,7 @@ def run() -> list[dict]:
     )
     jax.block_until_ready(out_all)
     cold_s = time.perf_counter() - t0
+    cold_hits = counters.persistent_cache_hits() - h0
     # the controlled run: same shapes, traced admission knobs -> same compile
     out_ctl = serving.sweep_serving(
         keys, pool_mask, p_gg, p_bb,
@@ -224,7 +228,7 @@ def run() -> list[dict]:
         "rows_per_sec": rows_per_sec,
         "baseline_rows_per_sec": baseline.get("rows_per_sec"),
         "slowdown_warned": slowdown_warned,
-        "cold_s": cold_s,
+        **cold_compile_metric(cold_s, cold_hits),
         "warm_s": warm_s,
         "warnings": warnings,
         "results": cells,

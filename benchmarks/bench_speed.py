@@ -8,7 +8,10 @@ whatever machine runs it: both numbers are fresh, the committed
 ``rows_per_sec`` of an older container never inflates the speedup.
 
 Three subprocess children (XLA device flags and persistent-cache config
-must precede jax import):
+must precede jax import).  They are sharding rehearsals on 8 virtual CPU
+devices, so each is pinned to ``JAX_PLATFORMS=cpu``: on a machine with a
+TPU the parent may already hold the chip, and a child that reached for it
+would fail or hang.
 
   * the MAIN child: sync vs async warm rows/sec, phase-seconds
     attribution, the bit-identity hard gate (async == sync, full-width
@@ -16,8 +19,9 @@ must precede jax import):
     ``input_output_alias`` in the compiled block-step HLO) and the tap
     overlap accounting (``tap.engine_pool.block_seconds`` from a
     tapped pipelined run);
-  * a COLD cache child + a WARM cache child sharing one
-    ``REPRO_COMPILE_CACHE`` dir: the warm process must re-run the same
+  * a COLD cache child + a WARM cache child sharing one fresh
+    ``JAX_COMPILATION_CACHE_DIR`` (fresh, so the cold child really is
+    cold): the warm process must re-run the same
     family with ZERO backend compile events through the unified counter
     (``repro.obs.counters.backend_compile_events``) — the cold-vs-warm
     process compile-time row.
@@ -68,6 +72,7 @@ _MARKER = "BENCH_SPEED "
 def _child_env(extra: dict | None = None) -> dict:
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={DEVICES}"
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = os.pathsep.join(
         [os.path.join(_ROOT, "src"), _ROOT]
         + env.get("PYTHONPATH", "").split(os.pathsep)
@@ -96,7 +101,7 @@ def _spawn(flag: str, env: dict) -> dict:
 def run() -> list[dict]:
     main = _spawn("--child-main", _child_env())
     with tempfile.TemporaryDirectory() as cache_dir:
-        env = _child_env({"REPRO_COMPILE_CACHE": cache_dir})
+        env = _child_env({"JAX_COMPILATION_CACHE_DIR": cache_dir})
         cold = _spawn("--child-cache", env)
         warm = _spawn("--child-cache", env)
     # warm restart of an already-cached family: the unified counter must
@@ -261,7 +266,7 @@ def _child_cache() -> None:
     # persistent-cache wiring BEFORE jax touches a backend
     from repro.launch.cache import enable_compile_cache
 
-    assert enable_compile_cache() is not None, "REPRO_COMPILE_CACHE unset"
+    enable_compile_cache()
 
     from repro import sweeps
     from repro.launch.mesh import make_sweep_mesh

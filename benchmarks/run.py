@@ -122,9 +122,9 @@ def main(argv: list[str] | None = None) -> None:
 
     import importlib
 
-    # REPRO_COMPILE_CACHE=<dir>: persistent XLA compile cache — one-compile-
-    # per-family survives process restarts (repro.launch.cache; the hit
-    # listener keeps the unified compile counters honest on warm restarts)
+    # persistent XLA compile cache — one-compile-per-family survives process
+    # restarts (repro.launch.cache; the hit listener keeps the unified
+    # compile counters honest on warm restarts)
     from repro.launch.cache import enable_compile_cache
 
     enable_compile_cache()

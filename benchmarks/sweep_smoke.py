@@ -4,7 +4,8 @@ Runs a tiny heterogeneous-K* registry grid through the full production path
 — 8 forced host devices, a 1-D ``jax.sharding`` batch mesh, ``round_chunk``
 blocking, multi-seed rows — in a subprocess (XLA device-count flags must be
 set before jax initialises, and the parent harness has already imported
-jax), asserts the sharded/chunked output matches per-row static-``LoadParams``
+jax), pinned to ``JAX_PLATFORMS=cpu`` because it is a sharding rehearsal on
+virtual devices and the parent may hold the machine's TPU, asserts the sharded/chunked output matches per-row static-``LoadParams``
 engine runs bit-for-bit (the shape-polymorphic engine's full-width
 invariant), and emits ``BENCH_sweep.json`` at the repo root with rows/sec,
 per-row allocator time AND the compile count per scenario family so the
@@ -53,6 +54,7 @@ _MARKER = "SWEEP_SMOKE_ROWS "
 def run() -> list[dict]:
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={DEVICES}"
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = os.pathsep.join(
         [os.path.join(_ROOT, "src"), _ROOT] + env.get("PYTHONPATH", "").split(os.pathsep)
     ).rstrip(os.pathsep)

@@ -54,9 +54,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .lagrange import (CodeSpec, _gf, decode_matrix, decode_matrix_jax,
-                       decode_matrix_modp_device, encode, generator_matrix,
-                       generator_matrix_modp_device)
+from .lagrange import (FLOAT_PRECISION, CodeSpec, _gf, decode_matrix,
+                       decode_matrix_jax, decode_matrix_modp_device, encode,
+                       generator_matrix, generator_matrix_modp_device)
 
 
 @dataclasses.dataclass
@@ -194,7 +194,8 @@ def _decode_on_time(
         d = decode_matrix_jax(spec, received)
         gathered = jnp.take(results, received, axis=0)        # (K*, *dims)
         ok = jnp.sum(on_time) >= kstar
-        return jnp.tensordot(d.astype(results.dtype), gathered, axes=1), ok
+        return jnp.tensordot(d.astype(results.dtype), gathered, axes=1,
+                             precision=FLOAT_PRECISION), ok
 
 
 def coded_matmul_device(
@@ -204,7 +205,8 @@ def coded_matmul_device(
 
     Returns ``(decoded, ok)``; ``decoded`` is meaningful only where ``ok``.
     """
-    results = jnp.einsum("vrc,c...->vr...", coded.x_tilde, w)
+    results = jnp.einsum("vrc,c...->vr...", coded.x_tilde, w,
+                         precision=FLOAT_PRECISION)
     return _decode_on_time(coded.spec, results, jnp.asarray(on_time))
 
 

@@ -153,10 +153,19 @@ def generator_matrix(spec: CodeSpec, dtype=jnp.float32) -> jnp.ndarray:
     return jnp.asarray(g, dtype=dtype)
 
 
+# Precision of the float coded round's encode, worker products and decode.
+# The decode multiplies the error of every encoded result by the condition
+# number of the received generator rows, and a TPU's default float32 matmul
+# is one bfloat16 pass, 2^15 float32 ulps.  HIGH (three bfloat16 passes)
+# meets chip_smoke's bound of 1000 ulps x cond at the EC2 size; on a CPU
+# every float32 matmul is full precision anyway.
+FLOAT_PRECISION = jax.lax.Precision.HIGH
+
+
 @partial(jax.jit, static_argnames=())
 def encode(generator: jnp.ndarray, data: jnp.ndarray) -> jnp.ndarray:
     """Encode stacked data chunks: (k, *dims) -> (nr, *dims)."""
-    return jnp.tensordot(generator, data, axes=1)
+    return jnp.tensordot(generator, data, axes=1, precision=FLOAT_PRECISION)
 
 
 def decode_matrix(

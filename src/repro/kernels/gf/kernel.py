@@ -10,10 +10,12 @@ Design (mirrors the float matmul revisiting pattern, VPU-only):
     the first visit (``pl.when(pl.program_id(2) == 0)``) and accumulated
     in-place after that — tiled accumulation, never a partial sum > 32 bits;
   * inside one visit, a ``fori_loop`` over the bk contraction steps does a
-    broadcast (bm, 1) x (1, bn) multiply-fold-add per step.  Products of two
-    31-bit residues are formed as four 16-bit-limb uint32 partial products
-    and reduced with the Mersenne fold 2^31 === 1 (shift-adds, no division,
-    no int64) — see :mod:`repro.kernels.gf.ref` for the arithmetic;
+    broadcast (bm, 1) x (1, bn) multiply-fold-add per step: the B row is a
+    dynamic sublane read of the ref, the A column a masked lane sum.
+    Products of two 31-bit residues are formed as four 16-bit-limb uint32
+    partial products and reduced with the Mersenne fold 2^31 === 1
+    (shift-adds, no division, no int64) — see :mod:`repro.kernels.gf.ref`
+    for the arithmetic;
   * the MXU is never touched: exact integer dots don't fit a float systolic
     array, so this is a pure VPU kernel with lanes padded to 128.  Zero
     padding is harmless (0 is the additive identity).
@@ -45,13 +47,17 @@ def _gf_matmul_kernel(a_ref, b_ref, out_ref, *, bk: int):
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    a = a_ref[...]                    # (bm, bk) uint32 residues
-    b = b_ref[...]                    # (bk, bn)
+    # residues are < 2^31, so the int32 view is exact; Mosaic reduces int32
+    # lanes but not uint32 ones
+    a = a_ref[...].astype(jnp.int32)  # (bm, bk)
+    lane = jax.lax.broadcasted_iota(jnp.int32, a.shape, 1)
 
     def body(i, acc):
-        col = jax.lax.dynamic_slice_in_dim(a, i, 1, axis=1)    # (bm, 1)
-        row = jax.lax.dynamic_slice_in_dim(b, i, 1, axis=0)    # (1, bn)
-        return add_gf(acc, mul_gf(col, row))
+        # column i of A as a masked lane sum (exact: one nonzero term) —
+        # Mosaic cannot slice a value at a traced lane offset
+        col = jnp.sum(jnp.where(lane == i, a, 0), axis=1, keepdims=True)
+        row = b_ref[pl.ds(i, 1), :]                            # (1, bn)
+        return add_gf(acc, mul_gf(col.astype(jnp.uint32), row))
 
     out_ref[...] = jax.lax.fori_loop(0, bk, body, out_ref[...])
 

@@ -43,8 +43,21 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 _LANES = 128
+
+
+def _shift_counts(pmf, counts):
+    """pmf[:, c] -> pmf[:, c - 1] along the counts lanes, 0 into count 0.
+
+    A lane rotate plus a select: Mosaic refuses unaligned lane slices of a
+    multi-row tile (the ``concatenate([0, pmf[:, :-1]])`` form aborts the TPU
+    compiler for any batch block above 8 rows).  The wrapped-around lane is
+    the last padded count, which is always 0 (counts above i are impossible
+    after i Bernoullis), and is masked anyway.
+    """
+    return jnp.where(counts == 0, 0.0, pltpu.roll(pmf, 1, 1))
 
 
 def _pb_kernel(probs_ref, out_ref, *, n: int, w: tuple[int, ...]):
@@ -59,17 +72,11 @@ def _pb_kernel(probs_ref, out_ref, *, n: int, w: tuple[int, ...]):
 
     for i in range(n):
         p_i = probs[:, i : i + 1]                       # (bb, 1), static slice
-        shifted = jnp.concatenate(
-            [jnp.zeros((bb, 1), jnp.float32), pmf[:, :-1]], axis=1
-        )
-        pmf = pmf * (1.0 - p_i) + shifted * p_i
+        pmf = pmf * (1.0 - p_i) + _shift_counts(pmf, counts) * p_i
         if w[i] > i + 1:                                # infeasible prefix
             continue                                    # (out stays 0)
-        # static slice to counts 0..n: summing the padded lanes too would pick
-        # a different XLA reduction tree and break bit-equality with the ref DP
         tail = jnp.sum(
-            jnp.where(counts[:, : n + 1] >= max(w[i], 0), pmf[:, : n + 1], 0.0),
-            axis=1, keepdims=True,
+            jnp.where(counts >= max(w[i], 0), pmf, 0.0), axis=1, keepdims=True,
         )                                               # (bb, 1)
         out = jnp.where(cols == i, tail, out)
 
@@ -97,14 +104,10 @@ def _pb_kernel_w(probs_ref, w_ref, out_ref, *, n: int):
 
     for i in range(n):
         p_i = probs[:, i : i + 1]                       # (bb, 1), static slice
-        shifted = jnp.concatenate(
-            [jnp.zeros((bb, 1), jnp.float32), pmf[:, :-1]], axis=1
-        )
-        pmf = pmf * (1.0 - p_i) + shifted * p_i
+        pmf = pmf * (1.0 - p_i) + _shift_counts(pmf, counts) * p_i
         w_i = w[:, i : i + 1]                           # (bb, 1), static slice
         tail = jnp.sum(
-            jnp.where(counts[:, : n + 1] >= jnp.maximum(w_i, 0),
-                      pmf[:, : n + 1], 0.0),
+            jnp.where(counts >= jnp.maximum(w_i, 0), pmf, 0.0),
             axis=1, keepdims=True,
         )                                               # (bb, 1)
         tail = jnp.where(w_i > i + 1, 0.0, tail)        # infeasible prefix

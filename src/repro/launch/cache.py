@@ -1,16 +1,19 @@
-"""Persistent XLA compilation cache wiring (opt-in via ``REPRO_COMPILE_CACHE``).
+"""Persistent XLA compilation cache wiring (on by default).
 
 The engine's one-compile-per-family story (``repro.sweeps`` traced-K*
 grouping) holds within a process; every restart still pays the full XLA
 compile for each family signature.  JAX ships a persistent compilation
 cache (supported on cpu/gpu/tpu backends) keyed by the computation
-fingerprint; pointing every entry process at a shared directory makes the
-per-family compile a one-time cost per container.
+fingerprint; pointing every entry process at one directory makes the
+per-family compile a one-time cost per machine.
 
 :func:`enable_compile_cache` is the single switch:
 
-  * reads ``REPRO_COMPILE_CACHE=<dir>`` (or an explicit ``path``) — unset
-    means disabled, return ``None``, zero config touched;
+  * the directory is JAX's own ``JAX_COMPILATION_CACHE_DIR`` when that is
+    set, so whoever runs the program places the cache; otherwise the fixed
+    ``<checkout>/.jax_cache`` (:data:`DEFAULT_DIR`, git-ignored).  The path
+    is part of every entry's key, so it never depends on a temporary
+    directory, a pid or a clock;
   * sets ``jax_compilation_cache_dir`` plus the two thresholds that
     default to skipping fast-compiling modules
     (``jax_persistent_cache_min_compile_time_secs`` and
@@ -22,16 +25,19 @@ per-family compile a one-time cost per container.
     the unified compile counter can tell "compiled" from "served from
     cache" (the warm-restart-records-0-compile-events contract).
 
-Callers: ``benchmarks/run.py`` and the launch CLIs
+Callers: ``benchmarks/run.py``, ``chip_smoke.py`` and the launch CLIs
 (``repro.launch.serve``, ``repro.launch.train``) call this before any
-jitted work; it is idempotent per process.
+jitted work — JAX decides once per process, at its first compile, whether
+the cache is in use.  It is idempotent per process.
 """
 
 from __future__ import annotations
 
 import os
 
-CACHE_ENV = "REPRO_COMPILE_CACHE"
+CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
+DEFAULT_DIR = os.path.abspath(
+    os.path.join(os.path.dirname(__file__), "..", "..", "..", ".jax_cache"))
 
 _HIT_EVENT = "/jax/compilation_cache/cache_hits"
 _MISS_EVENT = "/jax/compilation_cache/cache_misses"
@@ -49,7 +55,7 @@ def _listener(event: str, **kwargs) -> None:
 
 
 def persistent_cache_misses() -> int:
-    """Persistent-cache misses observed this process (0 unless enabled)."""
+    """Persistent-cache misses observed this process (0 until enabled)."""
     return int(_STATE["misses"])
 
 
@@ -58,18 +64,19 @@ def cache_dir() -> str | None:
     return _STATE["enabled_dir"]
 
 
-def enable_compile_cache(path: str | None = None) -> str | None:
-    """Enable the persistent compilation cache if configured; returns the dir.
+def resolve_cache_dir() -> str:
+    """``$JAX_COMPILATION_CACHE_DIR`` if set, else :data:`DEFAULT_DIR`."""
+    return os.path.abspath(os.environ.get(CACHE_ENV) or DEFAULT_DIR)
 
-    ``path`` overrides the ``REPRO_COMPILE_CACHE`` environment variable.
-    Returns ``None`` (and changes nothing) when neither is set.  Safe to
-    call repeatedly; re-enabling with a DIFFERENT directory raises — a
-    process mixing cache directories would double-count its own compiles.
+
+def enable_compile_cache() -> str:
+    """Enable the persistent compilation cache; returns its directory.
+
+    Safe to call repeatedly; re-enabling after ``JAX_COMPILATION_CACHE_DIR``
+    moved raises — a process mixing cache directories would double-count its
+    own compiles.
     """
-    target = path if path is not None else os.environ.get(CACHE_ENV)
-    if not target:
-        return None
-    target = os.path.abspath(target)
+    target = resolve_cache_dir()
     if _STATE["enabled_dir"] is not None:
         if _STATE["enabled_dir"] != target:
             raise RuntimeError(

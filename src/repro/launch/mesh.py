@@ -27,7 +27,7 @@ import numpy as np
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
@@ -38,7 +38,14 @@ def make_host_mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
             f"mesh {shape} needs {n} devices, found {len(jax.devices())} "
             "(set XLA_FLAGS=--xla_force_host_platform_device_count=N first)"
         )
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
+
+
+def _auto_mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
+    # Auto axes: the models place data with with_sharding_constraint and let
+    # GSPMD propagate the rest; jax.make_mesh defaults to Explicit axes
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_sweep_mesh(num_devices: int | None = None):

@@ -99,12 +99,6 @@ def main(argv=None) -> dict:
     args = ap.parse_args(argv)
     if args.smoke:
         args.rounds = min(args.rounds, 64)
-    # REPRO_COMPILE_CACHE=<dir>: persistent XLA compile cache, so restarting
-    # the CLI on an already-seen config skips the cold compile entirely
-    from repro.launch.cache import enable_compile_cache
-
-    enable_compile_cache()
-
     spec = CodeSpec(args.n, args.r, args.k, deg_f=args.deg_f)
     lp = LoadParams(
         n=args.n, kstar=spec.recovery_threshold,
@@ -179,4 +173,11 @@ def main(argv=None) -> dict:
 
 
 if __name__ == "__main__":
+    # persistent XLA compile cache (JAX_COMPILATION_CACHE_DIR, else the
+    # checkout's .jax_cache): a restart on a seen config skips the compile.
+    # Process-wide, so the CLI entry sets it, not main() (which callers and
+    # tests run in-process).
+    from repro.launch.cache import enable_compile_cache
+
+    enable_compile_cache()
     main()

@@ -46,11 +46,6 @@ def main(argv=None) -> dict:
     ap.add_argument("--dp-shards", type=int, default=8)
     ap.add_argument("--compress", default="none", choices=["none", "int8", "topk"])
     args = ap.parse_args(argv)
-    # REPRO_COMPILE_CACHE=<dir>: persistent XLA compile cache across restarts
-    from repro.launch.cache import enable_compile_cache
-
-    enable_compile_cache()
-
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     cfg = dataclasses.replace(cfg, microbatch=1)
     cell = ShapeCell("cli", args.seq, args.batch, "train")
@@ -139,4 +134,9 @@ def main(argv=None) -> dict:
 
 
 if __name__ == "__main__":
+    # persistent XLA compile cache across restarts (repro.launch.cache);
+    # process-wide, so the CLI entry sets it rather than main()
+    from repro.launch.cache import enable_compile_cache
+
+    enable_compile_cache()
     main()

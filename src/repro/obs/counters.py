@@ -19,14 +19,14 @@ same hook.
 
 Persistent-compilation-cache awareness: a jit trace-cache entry appears
 whether XLA actually compiled or the persistent cache
-(``REPRO_COMPILE_CACHE``; see :mod:`repro.launch.cache`) served the
+(see :mod:`repro.launch.cache`) served the
 executable — so a warm-restart process would otherwise look like it
 recompiled everything.  :func:`note_persistent_cache_hits` is fed by the
 ``jax.monitoring`` listener the cache layer installs; executors subtract
 the hit delta from the trace-cache delta
 (``max(trace_delta - hit_delta, 0)``) before attributing compile events,
 so "0 new compile events on a warm restart" is a real, measurable claim.
-With the cache disabled (the default) the hit counter stays 0 and every
+With the cache not enabled in a process the hit counter stays 0 and every
 delta reduces to the plain trace-cache delta.
 """
 

@@ -13,8 +13,10 @@ assert on).
 Sharding: sweep rows are embarrassingly parallel, so the executor lays the
 flat (scenarios x seeds) batch over the ``"batch"`` axis of a 1-D
 ``jax.sharding`` mesh (:func:`repro.launch.mesh.make_sweep_mesh`) by
-device_put-ing every batch leaf with ``NamedSharding(mesh, P("batch"))`` —
-the jitted computation then partitions itself over the data.  Batches are
+device_put-ing every batch leaf with ``NamedSharding(mesh, P("batch"))``,
+and each device runs the per-row program on its own rows
+(:func:`_rows_per_shard`, a ``shard_map``: GSPMD cannot partition the
+Pallas allocator kernel the engine calls on a TPU).  Batches are
 padded (by repeating the last row) to a multiple of the mesh size; padded
 rows are sliced off the result, so sharded output is bit-identical to the
 unsharded :func:`repro.core.throughput.sweep` on the same keys.
@@ -44,9 +46,28 @@ from repro.obs import metrics as _metrics
 from .registry import ScenarioBatch, SweepGroup
 
 
+def _rows_per_shard(fn, mesh: Mesh | None):
+    """``fn`` over batch rows, run shard by shard on ``mesh`` (None: as is).
+
+    Rows are independent, so each device running ``fn`` on its own rows is
+    bit-identical to the whole batch at once.  Every argument and output is
+    split along its leading (row) axis; values ``fn`` closes over are
+    replicated.
+    """
+    if mesh is None:
+        return fn
+    rows = PartitionSpec("batch")
+
+    def per_shard(*args):
+        return jax.shard_map(fn, mesh=mesh, in_specs=(rows,) * len(args),
+                             out_specs=rows, check_vma=False)(*args)
+
+    return per_shard
+
+
 @partial(jax.jit,
          static_argnames=("rounds", "strategies", "round_chunk", "telemetry",
-                          "tap", "tap_stride"))
+                          "tap", "tap_stride", "mesh"))
 def _run_group(
     keys: jnp.ndarray,
     p_gg: jnp.ndarray,
@@ -62,6 +83,7 @@ def _run_group(
     telemetry: bool = False,
     tap: bool = False,
     tap_stride: int | None = None,
+    mesh: Mesh | None = None,
 ):
     """(B,) rows -> (B, rounds, S) success indicators, one XLA computation."""
     fn = partial(
@@ -71,17 +93,17 @@ def _run_group(
     )
     if tap:
         rows = jnp.arange(keys.shape[0], dtype=jnp.int32)
-        return jax.vmap(
+        return _rows_per_shard(jax.vmap(
             lambda k, pg, pb, mg, mb, d, pl, ri: fn(
                 k, pool=pl, p_gg=pg, p_bb=pb, mu_g=mg, mu_b=mb, deadline=d,
                 tap_row=ri,
             )
-        )(keys, p_gg, p_bb, mu_g, mu_b, deadline, pool, rows)
-    return jax.vmap(
+        ), mesh)(keys, p_gg, p_bb, mu_g, mu_b, deadline, pool, rows)
+    return _rows_per_shard(jax.vmap(
         lambda k, pg, pb, mg, mb, d, pl: fn(
             k, pool=pl, p_gg=pg, p_bb=pb, mu_g=mg, mu_b=mb, deadline=d
         )
-    )(keys, p_gg, p_bb, mu_g, mu_b, deadline, pool)
+    ), mesh)(keys, p_gg, p_bb, mu_g, mu_b, deadline, pool)
 
 
 _obs_counters.register_compiled("sweeps.run_group", _run_group)
@@ -262,7 +284,8 @@ def _prepare_group(
 
 
 @partial(jax.jit,
-         static_argnames=("rounds", "strategies", "round_chunk", "tap"),
+         static_argnames=("rounds", "strategies", "round_chunk", "tap",
+                          "mesh"),
          donate_argnums=(0, 1, 2))
 def _block_step(
     succ_cum: jnp.ndarray,     # (B, S) int32 — DONATED
@@ -281,6 +304,7 @@ def _block_step(
     strategies: tuple[str, ...],
     round_chunk: int,
     tap: bool,
+    mesh: Mesh | None = None,
 ):
     """Round block ``block_i`` for every row: donated carries + (B, m, S) succ.
 
@@ -331,8 +355,9 @@ def _block_step(
             )
         return succ_c, err_c, tok, succ_b
 
-    return jax.vmap(row)(succ_cum, err_cum, token, states, round_keys,
-                         p_alloc, est, pack_f, pack_i, mask, rows_idx)
+    return _rows_per_shard(jax.vmap(row), mesh)(
+        succ_cum, err_cum, token, states, round_keys, p_alloc, est, pack_f,
+        pack_i, mask, rows_idx)
 
 
 _obs_counters.register_compiled("sweeps.prepare_group", _prepare_group)
@@ -384,6 +409,7 @@ def _run_group_pipelined(
             succ_cum, err_cum, token, jnp.asarray(bi, jnp.int32),
             states, round_keys, p_alloc, est, pack_f, pack_i, mask,
             rounds=rounds, strategies=strategies, round_chunk=chunk, tap=tap,
+            mesh=mesh,
         )
         dispatch_s += time.perf_counter() - t0
         inflight.append((bi, succ_b))
@@ -430,7 +456,7 @@ def pipeline_block_hlo(
         succ0, err0, tok0, jnp.asarray(0, jnp.int32),
         states, round_keys, p_alloc, est, pack_f, pack_i, mask,
         rounds=group.rounds, strategies=group.strategies, round_chunk=chunk,
-        tap=tap,
+        tap=tap, mesh=mesh,
     )
     return lowered.compile().as_text()
 
@@ -511,7 +537,7 @@ def run_group(
             batch.deadline, batch.pool,
             rounds=group.rounds, strategies=group.strategies,
             round_chunk=round_chunk, telemetry=telemetry,
-            tap=tap, tap_stride=tap_stride,
+            tap=tap, tap_stride=tap_stride, mesh=mesh,
         )
         out = jax.block_until_ready(out)
     # a trace-cache entry served from the persistent compilation cache

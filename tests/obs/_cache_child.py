@@ -1,20 +1,22 @@
 """Child process for the persistent compile-cache warm-restart test.
 
-Usage: ``python _cache_child.py <cache_dir>``.  Enables the cache at
-``cache_dir``, runs one sweep group (the same family/shapes every
+Usage: ``JAX_COMPILATION_CACHE_DIR=<cache_dir> python _cache_child.py
+<cache_dir>``.  Enables the cache, checks that it landed in ``cache_dir``,
+runs one sweep group (the same family/shapes every
 invocation), and prints one JSON line with the unified compile accounting:
 trace-cache entries, persistent-cache hits, and the backend compile events
 (trace entries minus hits — what ``record_compile`` attributes).
 """
 
 import json
+import os
 import sys
 
 
 def main() -> None:
     from repro.launch.cache import enable_compile_cache, persistent_cache_misses
 
-    assert enable_compile_cache(sys.argv[1]) is not None
+    assert enable_compile_cache() == os.path.abspath(sys.argv[1])
 
     from repro.obs import counters
     from repro.obs.metrics import DEFAULT as registry

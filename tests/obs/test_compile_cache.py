@@ -1,6 +1,6 @@
 """Persistent compile cache (repro.launch.cache): warm restarts compile 0.
 
-Two child processes share one ``REPRO_COMPILE_CACHE`` directory and run the
+Two child processes share one ``JAX_COMPILATION_CACHE_DIR`` directory and run the
 SAME sweep family.  The cold child populates the cache (real backend
 compiles, 0 hits); the warm child must serve every computation from the
 persistent cache — 0 backend compile events through the unified counter
@@ -22,6 +22,7 @@ def _child(cache_dir: str) -> dict:
     env = dict(os.environ)
     env.pop("XLA_FLAGS", None)
     env["PYTHONPATH"] = os.path.join(_HERE, "..", "..", "src")
+    env["JAX_COMPILATION_CACHE_DIR"] = cache_dir
     proc = subprocess.run(
         [sys.executable, os.path.join(_HERE, "_cache_child.py"), cache_dir],
         capture_output=True, text=True, timeout=600, env=env,
@@ -54,11 +55,14 @@ def test_enable_is_idempotent_but_rejects_redirect(tmp_path, monkeypatch):
     monkeypatch.setattr(cache_mod, "_STATE",
                         {"enabled_dir": None, "listener": True, "misses": 0})
     d1 = str(tmp_path / "a")
+    monkeypatch.setenv(cache_mod.CACHE_ENV, d1)
     try:
-        assert cache_mod.enable_compile_cache(d1) == os.path.abspath(d1)
-        assert cache_mod.enable_compile_cache(d1) == os.path.abspath(d1)
+        assert cache_mod.enable_compile_cache() == os.path.abspath(d1)
+        assert cache_mod.enable_compile_cache() == os.path.abspath(d1)
+        assert os.path.isdir(d1)
+        monkeypatch.setenv(cache_mod.CACHE_ENV, str(tmp_path / "b"))
         with pytest.raises(RuntimeError, match="already enabled"):
-            cache_mod.enable_compile_cache(str(tmp_path / "b"))
+            cache_mod.enable_compile_cache()
     finally:
         # tmp_path dies with the test; leaving the global cache dir pointed
         # at it would make every later compile in this process write there
@@ -68,10 +72,14 @@ def test_enable_is_idempotent_but_rejects_redirect(tmp_path, monkeypatch):
 
 
 def test_disabled_when_env_unset(monkeypatch):
+    """Nothing is enabled until asked; with the variable unset the cache
+    goes to the fixed ``<checkout>/.jax_cache``, never a per-run path."""
     from repro.launch import cache as cache_mod
 
     monkeypatch.delenv(cache_mod.CACHE_ENV, raising=False)
     monkeypatch.setattr(cache_mod, "_STATE",
                         {"enabled_dir": None, "listener": True, "misses": 0})
-    assert cache_mod.enable_compile_cache() is None
     assert cache_mod.cache_dir() is None
+    root = os.path.abspath(os.path.join(_HERE, "..", ".."))
+    assert cache_mod.resolve_cache_dir() == os.path.join(root, ".jax_cache")
+    assert cache_mod.resolve_cache_dir() == cache_mod.DEFAULT_DIR

@@ -4,6 +4,8 @@ import os
 import subprocess
 import sys
 
+import pytest
+
 _ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
@@ -186,6 +188,23 @@ def test_committed_bench_faults_manifest_shape_and_invariants():
         # containment, cell by cell, in the committed rates
         assert cell["recovered_conserve"] >= cell["recovered_aon"]
         assert 0.0 <= cell["served_any"] <= 1.0
+
+
+@pytest.mark.parametrize("hits,key", [(0, "cold_s"), (3, "cold_cached_s")])
+def test_cold_call_served_by_the_compile_cache_stays_out_of_the_cold_trend(
+        hits, key):
+    """bench_faults/bench_serving run in the benchmarks.run process, where
+    the persistent compile cache is on: a first call that loaded its family
+    from the cache is recorded apart, so the ``cold_s`` trajectory in
+    BENCH_history.jsonl only holds real compiles."""
+    sys.path.insert(0, _ROOT)
+    from benchmarks._softgate import cold_compile_metric
+    from repro.obs import history
+
+    rec = {"bench": "bench_faults", "metrics": cold_compile_metric(1.5, hits)}
+    assert rec["metrics"] == {key: 1.5, "cold_cache_hits": hits}
+    series = history._series([rec])["bench_faults"]
+    assert series == {key: [1.5]}
 
 
 def test_bench_serving_is_a_registered_target_and_listed():
