@@ -1,0 +1,10 @@
+"""Device nanoseconds under the ``repro.allocate`` scope of the serving
+program (``lea.allocate_queue`` inside the round scan) per simulated
+row-round."""
+
+
+def read(view):
+    ns = view.scope_ns("repro.allocate")
+    if ns <= 0:
+        return None
+    return ns / (view.n_calls * view.info["row_rounds_per_call"])
