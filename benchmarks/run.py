@@ -130,8 +130,9 @@ def main(argv: list[str] | None = None) -> None:
     enable_compile_cache()
 
     # REPRO_PROFILE=<dir> wraps the whole selection in a jax.profiler trace;
-    # each suite gets a host-side TraceAnnotation span (repro.obs.profiling)
-    from repro.obs import annotate, profile_trace
+    # each suite is a host span of its own (timed opens one, under the name
+    # of its histogram)
+    from repro.obs import profile_trace
     from repro.obs.metrics import DEFAULT as _metrics
     from repro.obs.metrics import ProgressLine, timed
 
@@ -143,7 +144,7 @@ def main(argv: list[str] | None = None) -> None:
         for i, (name, module, _) in enumerate(selected):
             try:
                 fn = importlib.import_module(f"benchmarks.{module}").run
-                with annotate(f"suite:{name}"), timed(f"bench.{name}"):
+                with timed(f"bench.{name}"):
                     rows = fn()
                 for row in rows:
                     print(f"{row['name']},{row['us_per_call']:.2f},\"{row['derived']}\"")

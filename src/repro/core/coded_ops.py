@@ -54,6 +54,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.obs.profiling import annotate
+from repro.obs.profiling import phase as _phase
+
 from .lagrange import (FLOAT_PRECISION, CodeSpec, _gf, decode_matrix,
                        decode_matrix_jax, decode_matrix_modp_device, encode,
                        generator_matrix, generator_matrix_modp_device)
@@ -186,8 +189,6 @@ def _decode_on_time(
     spec: CodeSpec, results: jnp.ndarray, on_time: jnp.ndarray
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Device decode: (nr, *dims) results + (nr,) bool -> ((k, *dims), ok)."""
-    from repro.obs.profiling import phase as _phase
-
     with _phase("decode"):
         kstar = spec.recovery_threshold
         received = received_indices(on_time, kstar)
@@ -376,8 +377,6 @@ def _decode_on_time_modp(
     spec: CodeSpec, results: jnp.ndarray, on_time: jnp.ndarray
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Exact device decode: (nr, *dims) residues + (nr,) bool -> ((k, *dims), ok)."""
-    from repro.obs.profiling import phase as _phase
-
     with _phase("decode"):
         gf = _gf()
         kstar = spec.recovery_threshold
@@ -387,6 +386,22 @@ def _decode_on_time_modp(
         ok = jnp.sum(on_time) >= kstar
         out = gf.from_gf(gf.matmul_gf(d, gathered.reshape(kstar, -1)))
         return out.reshape((spec.k,) + results.shape[1:]), ok
+
+
+@jax.jit
+def _products_modp(x_tilde: jnp.ndarray, w: jnp.ndarray) -> jnp.ndarray:
+    """Every worker's exact products: (nr, rows, cols) @ (cols[, d]) -> (nr, rows, d).
+
+    One exact GF(p) GEMM across all chunks, jitted so that the
+    ``repro.products`` scope names its device operations.
+    """
+    with _phase("products"):
+        gf = _gf()
+        w2 = w[:, None] if w.ndim == 1 else w              # (cols, d)
+        nr, rows = x_tilde.shape[0], x_tilde.shape[1]
+        flat = x_tilde.reshape(nr * rows, -1)              # (nr*rows, cols)
+        results = gf.from_gf(gf.matmul_gf(flat, w2))       # (nr*rows, d)
+        return results.reshape(nr, rows, w2.shape[1])
 
 
 def coded_matmul_exact(
@@ -404,17 +419,14 @@ def coded_matmul_exact(
     equality with the numpy ``matmul_modp``/``decode_matrix_modp`` pipeline
     whenever ``ok`` (jit cannot raise data-dependently, so short rounds
     return ``ok=False`` instead of the eager path's ``TimeoutError``).
+    The dispatch of the round, up to the return of its device arrays, is
+    one ``repro.round`` host span of a profiler trace.
     """
-    gf = _gf()
-    w = jnp.asarray(w)
-    squeeze = w.ndim == 1
-    w2 = w[:, None] if squeeze else w                      # (cols, d)
-    nr, rows = coded.x_tilde.shape[0], coded.x_tilde.shape[1]
-    flat = coded.x_tilde.reshape(nr * rows, -1)            # (nr*rows, cols)
-    results = gf.from_gf(gf.matmul_gf(flat, w2))           # (nr*rows, d)
-    results = results.reshape(nr, rows, w2.shape[1])
-    out, ok = _decode_on_time_modp(coded.spec, results, jnp.asarray(on_time))
-    return (out[..., 0] if squeeze else out), ok
+    with annotate("repro.round"):
+        w = jnp.asarray(w)
+        results = _products_modp(coded.x_tilde, w)
+        out, ok = _decode_on_time_modp(coded.spec, results, jnp.asarray(on_time))
+        return (out[..., 0] if w.ndim == 1 else out), ok
 
 
 def coded_linear_gradient_modp(

@@ -60,7 +60,8 @@ from __future__ import annotations
 import jax.numpy as jnp
 
 from repro.core.coded_ops import (CodedDataset, CodedDatasetModp,
-                                  _decode_on_time, _decode_on_time_modp)
+                                  _decode_on_time, _decode_on_time_modp,
+                                  _products_modp)
 
 from .channels import FaultTrace
 
@@ -172,23 +173,16 @@ def coded_matmul_exact_packets(
     """Per-packet EXACT GF(p) decode — the finite-field twin of
     :func:`coded_matmul_packets`.
 
-    Same packet decoupling, same per-packet ``ok`` flags; each packet block
+    Same packet decoupling, same per-packet ``ok`` flags; the workers'
+    products are the round's jitted ``_products_modp`` and each packet block
     runs the existing jitted ``_decode_on_time_modp`` traced-pattern device
     decode, so at ``packets=1`` with a full mask the computation — and its
     bit-exactness against the numpy modp oracle — is exactly
     :func:`~repro.core.coded_ops.coded_matmul_exact`'s.
     """
-    from repro.core.lagrange import _gf
-
-    gf = _gf()
     packets = packet_masks.shape[-1]
     w = jnp.asarray(w)
-    squeeze = w.ndim == 1
-    w2 = w[:, None] if squeeze else w
-    nr, rows = coded.x_tilde.shape[0], coded.x_tilde.shape[1]
-    flat = coded.x_tilde.reshape(nr * rows, -1)
-    results = gf.from_gf(gf.matmul_gf(flat, w2))
-    results = results.reshape(nr, rows, w2.shape[1])
+    results = _products_modp(coded.x_tilde, w)
     rp = _split_rows(results, packets)
     outs, oks = [], []
     for q in range(packets):
@@ -198,4 +192,4 @@ def coded_matmul_exact_packets(
         outs.append(out_q)
         oks.append(ok_q)
     out = jnp.concatenate(outs, axis=1)
-    return (out[..., 0] if squeeze else out), jnp.stack(oks)
+    return (out[..., 0] if w.ndim == 1 else out), jnp.stack(oks)

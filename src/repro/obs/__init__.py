@@ -25,10 +25,16 @@ on top of those streams:
     flag, jax/jaxlib versions, backend/device, caller-supplied timestamp
     — stamped into every ``BENCH_*.json`` by
     :func:`repro.sweeps.results.write_manifest`;
-  * :mod:`~repro.obs.profiling`  — ``jax.named_scope`` phase spans inside
-    the engines (trajectory sample -> policy replay -> allocate -> score
-    -> decode), host-side ``jax.profiler.TraceAnnotation`` spans, and a
-    ``REPRO_PROFILE=<dir>``-gated profiler-trace context manager.
+  * :mod:`~repro.obs.profiling`  — the span catalogue and its two
+    primitives: :func:`phase`, a ``jax.named_scope`` ``repro.<name>``
+    device scope inside a traced program (``DEVICE_SCOPES``: trajectory
+    sample -> policy replay -> allocate -> score -> decode in the engines,
+    the coded round's products, the serving queue's admit / edf_order /
+    release and its admission DP), and :func:`annotate`, the one host-side
+    ``jax.profiler.TraceAnnotation`` span (``HOST_SPANS``: the executor's
+    run_group / run_pipelined / pull, the sweep summarize, the coded
+    round's dispatch); plus a ``REPRO_PROFILE=<dir>``-gated
+    profiler-trace context manager.
 
 The LIVE tier (PR 9) sits next to the post-hoc ``telemetry=`` streams:
 
@@ -41,8 +47,8 @@ The LIVE tier (PR 9) sits next to the post-hoc ``telemetry=`` streams:
   * :mod:`~repro.obs.metrics`    — host metrics registry (named counters /
     gauges / histograms under a strict naming convention) with JSONL,
     Prometheus-exposition and stderr progress-line sinks, plus per-phase
-    wall-clock / compile-time attribution (:func:`timed`,
-    :func:`record_compile`);
+    wall-clock / compile-time attribution (:func:`timed`, which opens an
+    :func:`annotate` span of the same name, and :func:`record_compile`);
   * :mod:`~repro.obs.history`    — ``BENCH_history.jsonl``: every
     :func:`repro.sweeps.results.write_manifest` appends a compact
     provenance-stamped record, and :func:`~repro.obs.history.trend_report`
@@ -63,8 +69,8 @@ from .history import (HISTORY_BASENAME, HISTORY_ENV, append_record,
                       trend_report)
 from .metrics import (DEFAULT as default_metrics, JsonlSink, MetricsRegistry,
                       ProgressLine, record_compile, tap_to_registry, timed)
-from .profiling import (PROFILE_ENV, annotate, phase, profile_dir,
-                        profile_trace)
+from .profiling import (DEVICE_SCOPES, HOST_SPANS, PROFILE_ENV, annotate,
+                        phase, profile_dir, profile_trace)
 from .provenance import provenance
 from .taps import (EVENT_STREAMS, TAP_ENGINES, add_tap, capture_taps,
                    remove_tap, tap_names, validate_event)
@@ -73,14 +79,14 @@ from .telemetry import (FaultTelemetry, ServingTelemetry, TelemetryFrame,
                         validate_trace, write_trace)
 
 __all__ = [
-    "EVENT_STREAMS", "FaultTelemetry", "HISTORY_BASENAME", "HISTORY_ENV",
-    "JsonlSink", "MetricsRegistry", "PROFILE_ENV", "ProgressLine",
-    "ServingTelemetry", "TAP_ENGINES", "TelemetryFrame", "add_tap",
-    "annotate", "append_record", "capture_taps", "compile_events",
-    "counter_names", "default_metrics", "history_path", "metric_streams",
-    "metric_table", "phase", "profile_dir", "profile_trace", "provenance",
-    "read_history", "record_compile", "record_from_manifest",
-    "register_compiled", "remove_tap", "serving_trace", "tap_names",
-    "tap_to_registry", "timed", "trend_report", "validate_event",
-    "validate_trace", "write_trace",
+    "DEVICE_SCOPES", "EVENT_STREAMS", "FaultTelemetry", "HISTORY_BASENAME",
+    "HISTORY_ENV", "HOST_SPANS", "JsonlSink", "MetricsRegistry",
+    "PROFILE_ENV", "ProgressLine", "ServingTelemetry", "TAP_ENGINES",
+    "TelemetryFrame", "add_tap", "annotate", "append_record",
+    "capture_taps", "compile_events", "counter_names", "default_metrics",
+    "history_path", "metric_streams", "metric_table", "phase",
+    "profile_dir", "profile_trace", "provenance", "read_history",
+    "record_compile", "record_from_manifest", "register_compiled",
+    "remove_tap", "serving_trace", "tap_names", "tap_to_registry", "timed",
+    "trend_report", "validate_event", "validate_trace", "write_trace",
 ]

@@ -17,7 +17,7 @@ three sinks:
 
 Naming convention (enforced): ``<component>.<subject>[.<detail>...]`` —
 lower-case, digits and underscores per segment, at least two dot-separated
-segments (``tap.engine_pool.events``, ``phase.sweeps_run_group.seconds``,
+segments (``tap.engine_pool.events``, ``repro.run_group.seconds``,
 ``compile.serving_sweep.events``).  The convention keeps exposition names
 collision-free after the dot->underscore flattening.
 
@@ -38,6 +38,8 @@ import time
 from typing import Any, Iterator
 
 import numpy as np
+
+from .profiling import annotate
 
 _NAME_RE = re.compile(r"^[a-z][a-z0-9_]*(\.[a-z][a-z0-9_]*)+$")
 
@@ -380,18 +382,21 @@ def tap_to_registry(registry: MetricsRegistry | None = None):
 
 @contextlib.contextmanager
 def timed(name: str, registry: MetricsRegistry | None = None) -> Iterator[None]:
-    """Observe the block's wall-clock into histogram ``<name>.seconds``.
+    """Open host span ``name`` and observe its wall-clock into ``<name>.seconds``.
 
-    The host-side phase-attribution hook: ``with timed("phase.sweeps_run_group")``
+    The host-side phase-attribution hook: ``with timed("repro.run_group")``
     around a jitted call records its wall-clock next to the compile counters
-    (see ``repro.sweeps.executor``).
+    (see ``repro.sweeps.executor``).  The span is
+    :func:`repro.obs.profiling.annotate`'s, so a profiler trace shows the
+    interval the histogram times, under the same name.
     """
     reg = registry or DEFAULT
-    t0 = time.perf_counter()
-    try:
-        yield
-    finally:
-        reg.histogram(f"{name}.seconds", time.perf_counter() - t0)
+    with annotate(name):
+        t0 = time.perf_counter()
+        try:
+            yield
+        finally:
+            reg.histogram(f"{name}.seconds", time.perf_counter() - t0)
 
 
 def record_compile(family: str, compiles: int, seconds: float,
@@ -401,7 +406,8 @@ def record_compile(family: str, compiles: int, seconds: float,
     Called by the executors around their group entry points: the compile
     counter delta goes to ``compile.<family>.events`` and — only when the
     call actually compiled — the wall-clock to ``compile.<family>.seconds``
-    (warm calls land in ``phase.<family>.seconds`` via :func:`timed`).
+    (warm calls land in the ``<span>.seconds`` histogram of the caller's
+    :func:`timed` span).
     """
     reg = registry or DEFAULT
     fam = family.replace(".", "_")

@@ -1,15 +1,20 @@
-"""Profiling hooks: named phase scopes, host spans, REPRO_PROFILE traces.
+"""Profiling hooks: named device scopes, host spans, REPRO_PROFILE traces.
 
 Three layers, all zero-cost when unused:
 
-  * :func:`phase` — ``jax.named_scope`` around the engine phases
-    (``trajectory`` -> ``policy_replay`` -> ``allocate`` -> ``score`` ->
-    ``decode``).  Pure trace-time metadata: the names land in the HLO (and
-    therefore in profiler timelines) and add NOTHING at runtime, so the
-    engines wrap their phases unconditionally.
-  * :func:`annotate` — a host-side ``jax.profiler.TraceAnnotation`` span
-    (e.g. around one benchmark target).  No-op unless a profiler trace is
-    being collected.
+  * :func:`phase` — ``jax.named_scope`` ``repro.<name>`` around a step of a
+    traced program.  Pure trace-time metadata: the names land in the HLO
+    (and therefore on the device operations of a profiler trace) and add
+    NOTHING at runtime, so the programs open them unconditionally.  A
+    scope reaches the device only when it is opened while the program is
+    traced: around an eager call of a jitted function it names nothing.
+    :data:`DEVICE_SCOPES` is the catalogue of every scope the programs
+    open; :func:`phase` refuses any other name.
+  * :func:`annotate` — a host-side ``jax.profiler.TraceAnnotation`` span,
+    the program's one host span primitive.  :func:`repro.obs.metrics.timed`
+    opens one under its own name.  :data:`HOST_SPANS` is the catalogue of
+    the spans the program opens.  No-op unless a profiler trace is being
+    collected.
   * :func:`profile_trace` — the collection gate: when the
     ``REPRO_PROFILE`` env var names a directory, the context manager wraps
     its body in ``jax.profiler.start_trace``/``stop_trace`` and dumps a
@@ -18,7 +23,10 @@ Three layers, all zero-cost when unused:
 
         REPRO_PROFILE=/tmp/trace python -m benchmarks.run bench_serving
 
-    profiles a whole target with the engine phases labelled.
+    profiles a whole target with the scopes and spans labelled.
+
+Host spans and device scopes share the trace's clock, and a trace reader
+finds them by substring, so no ``repro.*`` name is a substring of another.
 
 jax is imported lazily so ``--list``-style cold paths never pay for it.
 """
@@ -31,8 +39,28 @@ from typing import Iterator
 
 PROFILE_ENV = "REPRO_PROFILE"
 
-# engine phases, in execution order — the catalogue ROADMAP documents
-ENGINE_PHASES = ("trajectory", "policy_replay", "allocate", "score", "decode")
+# every device scope, ``repro.<name>`` in the HLO, and where it is opened
+DEVICE_SCOPES = (
+    "trajectory",     # core/throughput.py: worker-state trajectory sample
+    "policy_replay",  # core/throughput.py: the policy's estimator replay
+    "allocate",       # core/throughput.py, serving/engine.py: LEA allocation
+    "score",          # core/throughput.py, serving/engine.py: on-time rule
+    "decode",         # core/coded_ops.py, faults/engine.py: master decode
+    "products",       # core/coded_ops.py: the workers' exact GF(p) products
+    "admit",          # serving/engine.py: admission gates + queue admit
+    "edf_order",      # serving/engine.py, inside allocate: EDF queue order
+    "release",        # serving/engine.py: slot recycling
+    "admission_dp",   # serving/engine.py: batched admission prediction
+)
+
+# every host span the program opens (through annotate, or timed)
+HOST_SPANS = (
+    "repro.run_group",      # sweeps/executor.py: one sync group, to ready
+    "repro.run_pipelined",  # sweeps/executor.py: one pipelined group
+    "repro.pull",           # sweeps/executor.py: a group's result to host
+    "repro.summarize",      # sweeps/results.py: the host fold of a sweep
+    "repro.round",          # core/coded_ops.py: dispatch of one exact round
+)
 
 
 def profile_dir() -> str | None:
@@ -41,9 +69,11 @@ def profile_dir() -> str | None:
 
 
 def phase(name: str):
-    """``jax.named_scope`` for one engine phase (trace-time metadata only)."""
+    """``jax.named_scope`` ``repro.<name>`` for one catalogued device scope."""
     import jax
 
+    if name not in DEVICE_SCOPES:
+        raise ValueError(f"{name!r} is not in DEVICE_SCOPES {DEVICE_SCOPES}")
     return jax.named_scope(f"repro.{name}")
 
 
@@ -73,7 +103,7 @@ def profile_trace(label: str = "repro") -> Iterator[str | None]:
     os.makedirs(out, exist_ok=True)
     jax.profiler.start_trace(out)
     try:
-        with jax.profiler.TraceAnnotation(label):
+        with annotate(label):
             yield out
     finally:
         jax.profiler.stop_trace()

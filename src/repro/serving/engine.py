@@ -157,9 +157,10 @@ def _simulate_serving_impl(
         t_cut = jnp.full((rounds, n), deadline, jnp.float32)       # (M, n)
 
     # -- admission prediction gate, ONE batched DP over (A, M) rows
-    p_succ = admission.predicted_success(
-        p_alloc, pool_mask, ks_m, eg_m, eb_m
-    )                                             # (A, M)
+    with _phase("admission_dp"):
+        p_succ = admission.predicted_success(
+            p_alloc, pool_mask, ks_m, eg_m, eb_m
+        )                                         # (A, M)
 
     n_valid = jnp.sum(pool_mask.astype(jnp.int32))
     t_idx = jnp.arange(rounds, dtype=jnp.int32)
@@ -169,19 +170,21 @@ def _simulate_serving_impl(
         (t, states_t, p_t, p_succ_t, count_t, ks_t, eg_t, eb_t, dl_t,
          thr_t, cap_t, tcut_t) = xs
         # (1) admission: prediction gate x capacity gate x free slots
-        m_active = admission.minimal_demand(q.occupied, q.kstar, q.ell_g)
-        room = admission.admission_room(
-            m_active, _ceil_div(ks_t, eg_t), n_valid, cap_t
-        )
-        want = jnp.where(
-            p_succ_t >= thr_t, jnp.minimum(count_t, room), 0
-        )
-        q, n_admit = rqueue.admit(q, t, want, ks_t, eg_t, eb_t, dl_t)
+        with _phase("admit"):
+            m_active = admission.minimal_demand(q.occupied, q.kstar, q.ell_g)
+            room = admission.admission_room(
+                m_active, _ceil_div(ks_t, eg_t), n_valid, cap_t
+            )
+            want = jnp.where(
+                p_succ_t >= thr_t, jnp.minimum(count_t, room), 0
+            )
+            q, n_admit = rqueue.admit(q, t, want, ks_t, eg_t, eb_t, dl_t)
         # (2) multi-job allocation: greedy EDF water-filling
         with _phase("allocate"):
+            with _phase("edf_order"):
+                order = rqueue.edf_order(q)
             loads, _i_star, feas = lea_mod.allocate_queue(
-                p_t, pool_mask, q.occupied, q.kstar, q.ell_g, q.ell_b,
-                rqueue.edf_order(q),
+                p_t, pool_mask, q.occupied, q.kstar, q.ell_g, q.ell_b, order,
             )                                     # (Q, n), (Q,), (Q,)
         # (3) score: the engine's on-time rule, per slot
         with _phase("score"):
@@ -200,7 +203,8 @@ def _simulate_serving_impl(
             + jnp.where(overdue, EVENT_EXPIRED, 0)
         ).astype(jnp.int32)
         sojourn_t = jnp.where(leave, t - q.arrival + 1, 0)
-        q = rqueue.release(q, leave)
+        with _phase("release"):
+            q = rqueue.release(q, leave)
         count_i = lambda m: jnp.sum(m.astype(jnp.int32))
         cnt = _Counters(
             admitted=cnt.admitted + n_admit,

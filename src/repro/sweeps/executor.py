@@ -42,6 +42,7 @@ from repro.core import throughput
 from repro.core.lea import PoolLoad
 from repro.obs import counters as _obs_counters
 from repro.obs import metrics as _metrics
+from repro.obs.profiling import annotate
 
 from .registry import ScenarioBatch, SweepGroup
 
@@ -179,11 +180,11 @@ def last_pipeline_stats() -> dict:
     """Host-loop accounting of the most recent pipelined run_group call.
 
     Keys: ``blocks``, ``round_chunk``, ``donated`` (runtime proof: the
-    donated carry buffer was consumed by the first block step), ``fold_s``
-    (host-side per-block result folding, overlapped with device compute),
-    ``dispatch_s`` (time spent enqueueing block steps), ``drain_s`` (the
-    final block_until_ready), ``shard_cached`` (the padded/sharded batch
-    came from the shard-once cache).
+    donated carry buffer was consumed by the first block step),
+    ``shard_cached`` (the padded/sharded batch came from the shard-once
+    cache).  The loop keeps no clock of its own: each block's copy to the
+    host is a ``repro.pull`` span of a profiler trace
+    (:mod:`repro.obs.profiling`).
     """
     return dict(_PIPELINE_STATS)
 
@@ -394,36 +395,28 @@ def _run_group_pipelined(
     first_carry = succ_cum
     host_blocks: list[np.ndarray | None] = [None] * n_blocks
     inflight: collections.deque = collections.deque()
-    fold_s = dispatch_s = 0.0
 
     def fold_oldest():
-        nonlocal fold_s
         j, sb = inflight.popleft()
-        t0 = time.perf_counter()
-        host_blocks[j] = np.asarray(sb)      # waits for block j only
-        fold_s += time.perf_counter() - t0
+        with annotate("repro.pull"):
+            host_blocks[j] = np.asarray(sb)  # waits for block j only
 
     for bi in range(n_blocks):
-        t0 = time.perf_counter()
         succ_cum, err_cum, token, succ_b = _block_step(
             succ_cum, err_cum, token, jnp.asarray(bi, jnp.int32),
             states, round_keys, p_alloc, est, pack_f, pack_i, mask,
             rounds=rounds, strategies=strategies, round_chunk=chunk, tap=tap,
             mesh=mesh,
         )
-        dispatch_s += time.perf_counter() - t0
         inflight.append((bi, succ_b))
         if len(inflight) >= PIPELINE_DEPTH:
             fold_oldest()
     while inflight:
         fold_oldest()
-    t0 = time.perf_counter()
     jax.block_until_ready((succ_cum, err_cum, token))
-    drain_s = time.perf_counter() - t0
 
     _PIPELINE_STATS.update(
         blocks=n_blocks, round_chunk=chunk, donated=bool(first_carry.is_deleted()),
-        fold_s=fold_s, dispatch_s=dispatch_s, drain_s=drain_s,
     )
     succ = (host_blocks[0] if n_blocks == 1
             else np.concatenate(host_blocks, axis=1))
@@ -479,9 +472,12 @@ def run_group(
     ``tap=True`` the engine streams per-row block aggregates to the
     registered tap handlers DURING the run (:mod:`repro.obs.taps`) — same
     bit-identity and one-compile contract.  Every call attributes its
-    wall-clock (``phase.sweeps_run_group.seconds``) and any compile events
-    it triggered (``compile.sweeps_run_group.*``) to the default metrics
-    registry (:mod:`repro.obs.metrics`).
+    wall-clock up to the device result (``repro.run_group.seconds``, or
+    ``repro.run_pipelined.seconds``) and any compile events it triggered
+    (``compile.sweeps_run_group.*``) to the default metrics registry
+    (:mod:`repro.obs.metrics`); in a profiler trace the same intervals are
+    host spans of those names, and the copy of the result to the host is a
+    ``repro.pull`` span.
 
     ``pipeline=True`` selects the async double-buffered path: shard-once
     batch cache, donated-carry block steps, host folds overlapped with the
@@ -511,7 +507,7 @@ def run_group(
             + _obs_counters.compile_events("sweeps.prepare_group")
         h0 = _obs_counters.persistent_cache_hits()
         t0 = time.perf_counter()
-        with _metrics.timed("phase.sweeps_pipeline"):
+        with _metrics.timed("repro.run_pipelined"):
             succ = _run_group_pipelined(
                 group, batch, b, mesh=mesh, round_chunk=round_chunk, tap=tap,
             )
@@ -531,7 +527,7 @@ def run_group(
     c0 = _obs_counters.compile_events("sweeps.run_group")
     h0 = _obs_counters.persistent_cache_hits()
     t0 = time.perf_counter()
-    with _metrics.timed("phase.sweeps_run_group"):
+    with _metrics.timed("repro.run_group"):
         out = _run_group(
             batch.keys, batch.p_gg, batch.p_bb, batch.mu_g, batch.mu_b,
             batch.deadline, batch.pool,
@@ -549,10 +545,11 @@ def run_group(
             - (_obs_counters.persistent_cache_hits() - h0), 0),
         time.perf_counter() - t0,
     )
-    if not telemetry:
-        return np.asarray(out[:b])
-    succ, frame = out
-    return np.asarray(succ[:b]), jax.tree.map(lambda x: np.asarray(x[:b]), frame)
+    with annotate("repro.pull"):
+        if not telemetry:
+            return np.asarray(out[:b])
+        succ, frame = out
+        return np.asarray(succ[:b]), jax.tree.map(lambda x: np.asarray(x[:b]), frame)
 
 
 def run_groups(

@@ -38,6 +38,7 @@ from typing import Any, Sequence
 import jax.numpy as jnp
 import numpy as np
 
+from repro.obs.profiling import annotate
 from repro.obs.provenance import provenance as _provenance_fn
 from repro.policies import regret as regret_mod
 
@@ -204,16 +205,21 @@ def summarize(
     *,
     scenario_order: Sequence[Scenario] | None = None,
 ) -> list[ScenarioResult]:
-    """Fold every group; optionally reorder to the original expansion order."""
+    """Fold every group; optionally reorder to the original expansion order.
+
+    The fold is one ``repro.summarize`` host span of a profiler trace.
+    """
     results: list[ScenarioResult] = []
-    for group, succ in zip(groups, succs):
-        results.extend(summarize_group(group, succ))
-    if scenario_order is not None:
-        # key on the scenario VALUE, not its name: distinct scenarios may
-        # share a name across concatenated expansions (e.g. the same family
-        # expanded twice with different rounds), and names must not alias
-        by_scenario = {r.scenario: r for r in results}
-        results = [by_scenario[sc] for sc in scenario_order]
+    with annotate("repro.summarize"):
+        for group, succ in zip(groups, succs):
+            results.extend(summarize_group(group, succ))
+        if scenario_order is not None:
+            # key on the scenario VALUE, not its name: distinct scenarios may
+            # share a name across concatenated expansions (e.g. the same
+            # family expanded twice with different rounds), and names must
+            # not alias
+            by_scenario = {r.scenario: r for r in results}
+            results = [by_scenario[sc] for sc in scenario_order]
     return results
 
 

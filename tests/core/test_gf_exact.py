@@ -136,6 +136,24 @@ def test_exact_decode_repetition_branch_vs_numpy():
     np.testing.assert_array_equal(np.asarray(out, np.int64), want)
 
 
+def test_exact_round_on_a_block_of_queries_vs_numpy():
+    """A (cols, d) block of queries goes through the same jitted products
+    and decode as one query, bit-identical to the numpy pipeline."""
+    spec = lcc.CodeSpec(5, 2, 4, 1)
+    rng = np.random.default_rng(11)
+    x = rng.integers(0, P, size=(spec.k, 3, 6), dtype=np.int64)
+    w = rng.integers(0, P, size=(6, 3), dtype=np.int64)
+    w[0, 0] = P - 1
+    coded = encode_dataset_modp(spec, jnp.asarray(x, jnp.int32))
+    on_time = np.zeros(spec.nr, bool)
+    on_time[rng.choice(spec.nr, size=spec.recovery_threshold, replace=False)] = True
+    out, ok = coded_matmul_exact(coded, jnp.asarray(w, jnp.int32), jnp.asarray(on_time))
+    assert bool(ok)
+    assert out.shape == (spec.k, 3, 3)
+    want = _np_pipeline(spec, np.asarray(coded.x_tilde, np.int64), w, on_time)
+    np.testing.assert_array_equal(np.asarray(out, np.int64), want)
+
+
 def test_short_round_reports_not_ok():
     spec = lcc.CodeSpec(5, 2, 4, 1)
     coded = encode_dataset_modp(

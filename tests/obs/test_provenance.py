@@ -55,6 +55,10 @@ def test_manifest_and_write_manifest_stamp_provenance(tmp_path):
 
 
 def test_counter_registry_names_and_totals():
+    # the subsystems register their compiled entry points when imported;
+    # import them here so the test does not hang on what ran before it
+    from repro import faults, serving, sweeps  # noqa: F401
+
     names = counter_names()
     for expected in ("engine.simulate_strategies_pool", "sweeps.run_group",
                      "faults.sweep", "serving.sweep"):
@@ -87,8 +91,13 @@ def test_profile_gating_and_phase_scope():
 
 
 def test_profile_trace_writes_a_trace_when_enabled(tmp_path):
-    from repro.obs import annotate, profile_trace
+    import pathlib
 
+    from jax.profiler import ProfileData
+
+    from repro.obs import MetricsRegistry, annotate, profile_trace, timed
+
+    reg = MetricsRegistry()
     old = os.environ.get(PROFILE_ENV)
     os.environ[PROFILE_ENV] = str(tmp_path)
     try:
@@ -98,6 +107,8 @@ def test_profile_trace_writes_a_trace_when_enabled(tmp_path):
             assert out == str(tmp_path)
             with annotate("span"):
                 jnp.arange(4).sum().block_until_ready()
+            with timed("phase.demo", reg):
+                jnp.arange(8).sum().block_until_ready()
     finally:
         if old is None:
             os.environ.pop(PROFILE_ENV, None)
@@ -106,3 +117,12 @@ def test_profile_trace_writes_a_trace_when_enabled(tmp_path):
     # jax.profiler drops its dump under plugins/profile/<run>/
     dumped = [p for p, _, files in os.walk(tmp_path) if files]
     assert dumped, "REPRO_PROFILE produced no trace files"
+    # both host spans are in the trace under their names, and timed still
+    # fills its histogram from the interval it spans
+    (path,) = pathlib.Path(tmp_path).rglob("*.xplane.pb")
+    host = {e.name for plane in ProfileData.from_file(str(path)).planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines for e in line.events}
+    assert {"test", "span", "phase.demo"} <= host
+    snap = reg.get("phase.demo.seconds")
+    assert snap["count"] == 1 and snap["sum"] > 0

@@ -47,17 +47,19 @@ def test_gitignore_covers_bytecode():
 
 def test_repo_root_is_free_of_generated_artifacts():
     """The root keeps ONLY the deliberate bench surface: ``BENCH_*.json``
-    manifests and ``BENCH_history.jsonl``.  Traces, logs and other
-    generated files live under ``benchmarks/artifacts/``."""
+    manifests, ``BENCH_history.jsonl``, the chip benchmark's description
+    ``BENCHMARK.json`` and its ledger ``PERF_LEDGER.jsonl``.  Traces, logs
+    and other generated files live under ``benchmarks/artifacts/``."""
     rooted = [f for f in _tracked_files() if "/" not in f]
     offenders = [
         f for f in rooted
         if f.endswith((".jsonl", ".log", ".csv", ".prof", ".trace"))
-        and f != "BENCH_history.jsonl"
+        and f not in ("BENCH_history.jsonl", "PERF_LEDGER.jsonl")
     ]
     offenders += [
         f for f in rooted
         if f.endswith(".json") and not f.startswith("BENCH_")
+        and f != "BENCHMARK.json"
     ]
     assert not offenders, f"generated artifacts tracked at repo root: {offenders}"
     # the trace moved out of the root for good
