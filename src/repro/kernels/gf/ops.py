@@ -8,8 +8,10 @@ Two entry points the coding layer uses:
 
 ``matmul_gf`` impls:
 
-  * ``impl="pallas"`` — the blocked VMEM kernel (TPU; ``interpret=True`` on
-    CPU for testing).
+  * ``impl="pallas"`` — the blocked VMEM kernels (TPU; ``interpret=True``
+    on CPU for testing): a lane contraction along A's rows for a narrow
+    right-hand side (n < 128: a round's products, the decode), outer
+    products for a wide one (the encode); see :mod:`.kernel`.
   * ``impl="dot"``    — the XLA fast path used on CPU/GPU: residues are
     decomposed into four 8-bit limbs and contracted with SIXTEEN float32
     GEMMs per K-chunk of 256 (256 * 255^2 < 2^24, so every float32 partial

@@ -51,6 +51,7 @@ DEVICE_SCOPES = (
     "edf_order",      # serving/engine.py, inside allocate: EDF queue order
     "release",        # serving/engine.py: slot recycling
     "admission_dp",   # serving/engine.py: batched admission prediction
+    "gf_matvec",      # kernels/gf/kernel.py: the GF(p) narrow-width body
 )
 
 # every host span the program opens (through annotate, or timed)

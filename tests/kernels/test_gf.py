@@ -8,9 +8,11 @@ the limb-decomposed dot path.
 
 import numpy as np
 import jax.numpy as jnp
+import pytest
 from hypothesis import given, settings, strategies as st
 
 from repro.kernels import gf
+from repro.kernels.gf import kernel as gf_kernel
 from repro.kernels.gf import ref as gf_ref
 from repro.kernels.gf.kernel import matmul_gf_pallas
 
@@ -301,3 +303,60 @@ def test_bmm_gf_pallas_interpret_bit_equal_dot():
         dot = np.asarray(gf.bmm_gf(jnp.asarray(a, jnp.int32),
                                    jnp.asarray(x, jnp.int32), impl="dot"))
         np.testing.assert_array_equal(pal, dot)
+
+
+# ---------------------------------------------------------------------------
+# the narrow body: lane contraction for n < 128
+# ---------------------------------------------------------------------------
+
+# m not a multiple of 8 and c not of 128; c past one K block of the narrow
+# tiles (several K visits, A's last column block ragged)
+_MANY_K = gf_kernel._NARROW_BK + 300
+
+
+@pytest.mark.parametrize("fill", ["random", "p_minus_1"])
+@pytest.mark.parametrize("m, c", [(75, 300), (37, _MANY_K), (1, 5)])
+@pytest.mark.parametrize("n", [1, 3, 25])
+def test_narrow_body_bit_equal_numpy(n, m, c, fill):
+    """Every residue at p - 1 stresses the fold and the exact lane reduction
+    (128 lanes of p - 1 overflow uint32 if summed whole)."""
+    rng = np.random.default_rng(m * 1000 + c + n)
+    if fill == "random":
+        a, b = _rand_residues(rng, (m, c)), _rand_residues(rng, (c, n))
+    else:
+        a, b = np.full((m, c), P - 1), np.full((c, n), P - 1)
+    got = matmul_gf_pallas(jnp.asarray(a, jnp.uint32), jnp.asarray(b, jnp.uint32),
+                           interpret=True)
+    np.testing.assert_array_equal(np.asarray(got, np.int64), _np_matmul_gf(a, b))
+
+
+@pytest.mark.parametrize("b, m, c, n", [(3, 75, 300, 1), (2, 40, 25, 3),
+                                        (2, 9, _MANY_K, 25)])
+def test_narrow_body_through_bmm_gf(b, m, c, n):
+    """The vmapped pallas_call lowers the narrow body too."""
+    rng = np.random.default_rng(b * m + c)
+    a = _rand_residues(rng, (b, m, c))
+    x = _rand_residues(rng, (b, c, n))
+    got = np.asarray(gf.bmm_gf(jnp.asarray(a, jnp.int32), jnp.asarray(x, jnp.int32),
+                               impl="pallas", interpret=True), np.int64)
+    for i in range(b):
+        np.testing.assert_array_equal(got[i], _np_matmul_gf(a[i], x[i]))
+
+
+def test_mul_by_zero_is_zero_for_any_uint32():
+    """The narrow body reads A's ragged column edge unpadded: on the chip the
+    lanes past it hold whatever the buffer held, and meet B's zero padding."""
+    x = jnp.asarray(np.array([0, 1, P - 1, P, P + 1, 2**31, 2**32 - 1,
+                              0xDEADBEEF], np.uint32))
+    np.testing.assert_array_equal(np.asarray(gf.mul_gf(x, jnp.uint32(0))), 0)
+
+
+@pytest.mark.parametrize("n, narrow", [(1, True), (25, True), (127, True),
+                                       (128, False), (200, False)])
+def test_width_cut_selects_the_body(n, narrow):
+    """n < 128 runs the narrow body, traced under ``repro.gf_matvec``;
+    n >= 128 runs the wide body, without it."""
+    a = jnp.zeros((16, 40), jnp.uint32)
+    b = jnp.zeros((40, n), jnp.uint32)
+    hlo = matmul_gf_pallas.lower(a, b, interpret=True).as_text(debug_info=True)
+    assert ("repro.gf_matvec" in hlo) == narrow

@@ -19,6 +19,7 @@ from jax.sharding import SingleDeviceSharding
 from repro.core import lea
 from repro.core.lagrange import CodeSpec
 from repro.core.lea import LoadParams
+from repro.kernels.gf import bmm_gf
 from repro.kernels.gf.kernel import matmul_gf_pallas
 from repro.kernels.poisson_binomial.kernel import (success_tails_pallas,
                                                    success_tails_pallas_w)
@@ -88,3 +89,18 @@ def test_gf_matmul_compiles_at_ec2_shapes(one_chip, stage):
     b = jax.ShapeDtypeStruct((c, n), jnp.uint32, sharding=one_chip)
     hlo = _compile(matmul_gf_pallas, a, b)
     assert "tpu_custom_call" in hlo
+    # the products and the decode are narrow (n < 128): the lane contraction
+    assert ("repro.gf_matvec" in hlo) == (stage != "encode")
+
+
+def test_gf_bmm_compiles_at_ec2_gradient_shape(one_chip):
+    """The exact gradient's vmapped per-chunk products, X~_v^T (X~_v w - y~_v):
+    (cols, rows) @ (rows, 1) for every one of the n r chunks, narrow body."""
+    a = jax.ShapeDtypeStruct((EC2.nr, EC2_COLS, EC2_ROWS), jnp.uint32,
+                             sharding=one_chip)
+    b = jax.ShapeDtypeStruct((EC2.nr, EC2_ROWS, 1), jnp.uint32,
+                             sharding=one_chip)
+    hlo = _compile(lambda x, y: bmm_gf(x, y, impl="pallas", interpret=False),
+                   a, b)
+    assert "tpu_custom_call" in hlo
+    assert "repro.gf_matvec" in hlo

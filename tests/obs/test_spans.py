@@ -16,6 +16,7 @@ from repro import serving, sweeps
 from repro.core.coded_ops import (_decode_on_time_modp, _products_modp,
                                   coded_matmul_exact, encode_dataset_modp)
 from repro.core.lagrange import CodeSpec
+from repro.kernels.gf.kernel import matmul_gf_pallas
 from repro.obs.profiling import DEVICE_SCOPES, HOST_SPANS, phase
 from repro.serving.engine import _run_serving_group
 from repro.sweeps import executor
@@ -87,6 +88,12 @@ def _coded_round_hlo() -> str:
             + decode.as_text(debug_info=True))
 
 
+def _gf_matvec_hlo() -> str:
+    a = jnp.zeros((16, 40), jnp.uint32)
+    b = jnp.zeros((40, 1), jnp.uint32)
+    return matmul_gf_pallas.lower(a, b, interpret=True).as_text(debug_info=True)
+
+
 PROGRAMS = {
     "sweep_group": (_sweep_group_hlo,
                     ("trajectory", "policy_replay", "allocate", "score")),
@@ -94,6 +101,7 @@ PROGRAMS = {
                       ("admission_dp", "admit", "allocate", "edf_order",
                        "score", "release")),
     "coded_round": (_coded_round_hlo, ("products", "decode")),
+    "gf_matvec": (_gf_matvec_hlo, ("gf_matvec",)),
 }
 
 
