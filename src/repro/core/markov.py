@@ -30,6 +30,13 @@ import jax
 import jax.numpy as jnp
 
 
+# Pools at least this wide step their trajectory sequentially: for a v5e
+# the associative scan's program compiled in time that grows with rounds x n
+# (390 s for 32 rows of 2,000 rounds x 4,000 workers, against 20 s stepped),
+# while a step over thousands of workers is work enough to hide the loop.
+SEQUENTIAL_N = 128
+
+
 def stationary_good_prob(p_gg: jnp.ndarray, p_bb: jnp.ndarray) -> jnp.ndarray:
     """pi_g = (1 - p_bb) / (2 - p_gg - p_bb) for an irreducible 2-state chain."""
     return (1.0 - p_bb) / (2.0 - p_gg - p_bb)
@@ -131,8 +138,11 @@ def sample_trajectory(
     batched Monte-Carlo engine).  Applying prefix t to the stationary draw s0
     gives state t.  Every round consumes the same per-key uniform draw and the
     composition is pure boolean selection, so trajectories are bit-identical
-    to :func:`sample_trajectory_scan` on the same key.
+    to :func:`sample_trajectory_scan` on the same key.  Pools of
+    ``SEQUENTIAL_N`` workers or more take that sequential sampler itself.
     """
+    if p_gg.shape[-1] >= SEQUENTIAL_N:
+        return sample_trajectory_scan(key, p_gg, p_bb, rounds, worker_mask)
     k0, k1 = jax.random.split(key)
     s0 = initial_states(k0, p_gg, p_bb)
     if rounds == 1:

@@ -45,6 +45,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.obs.profiling import phase as _phase
+
 _LANES = 128
 
 
@@ -181,6 +183,175 @@ def success_tails_pallas_w(
         interpret=interpret,
     )(probs_p, w_p)
     return out[:b, :n]
+
+
+# -- the wide body: fleets of hundreds to thousands of workers ----------------
+
+# A batch tile of the wide body: rows on sublanes, so each step's update of a
+# 128-count tile is bb / 8 vregs; its pmf, probability, threshold and output
+# blocks are (n_pad / 128, bb, 128) words each, 4 MiB at n = 4,000.  On a
+# v5e a tile step cost about 115 ns at every bb from 8 to 64 rows (61 us a
+# row at 64, 4,000 workers): the step's overhead, not its vregs, set the
+# pace, so the tile is as tall as an engine block of rounds.
+_WIDE_BB = 256
+# No threshold of any row reaches this: the floor offset of a row with no
+# feasible prefix, so that it never lowers its tile's floor.
+_NO_FLOOR = 2**30
+
+
+def _pb_wide_kernel(bounds_ref, probs_ref, w_ref, top_ref, out_ref, pmf_ref,
+                    *, n: int):
+    """The same DP with the worker loop rolled and the count axis capped.
+
+    Blocks: ``probs_ref``, ``w_ref``, ``out_ref`` are (n_chunks, bb, 128),
+    chunk k holding workers / prefixes 128k .. 128k + 127 of bb rows;
+    ``top_ref`` (bb, 128) is each row's top bin t, repeated along the lanes;
+    ``bounds_ref`` (SMEM) holds the tile's floor offset s and largest top
+    bin.  ``pmf_ref`` holds count c of every row in slot c // 128 + 1,
+    lane c % 128; slot 0 stays zero, the counts below 0.
+
+    Capping, exact for every tail the kernel writes: counts at or above t
+    fold into count t (absorbing: it keeps its mass and takes the inflow
+    from t - 1), and after worker i only counts in [i + s, min(i + 1, t)]
+    are updated.  A count below i + s cannot reach any later feasible
+    threshold, so its value never reaches a count that is summed: it is
+    left stale.
+    """
+    bb = top_ref.shape[0]
+    floor_off = bounds_ref[0, 0]
+    top_max = bounds_ref[0, 1]
+    lane = jax.lax.broadcasted_iota(jnp.int32, (bb, _LANES), 1)
+    top = top_ref[...]
+    zeros = jnp.zeros((bb, _LANES), jnp.float32)
+
+    def clear(t, carry):
+        pmf_ref[t] = zeros
+        return carry
+
+    jax.lax.fori_loop(0, pmf_ref.shape[0], clear, 0)
+    pmf_ref[1] = (lane == 0).astype(jnp.float32)        # point mass at count 0
+
+    def chunk(k, carry):
+        p_chunk = probs_ref[k]
+        w_chunk = w_ref[k]
+
+        def step(j, out_chunk):
+            i = k * _LANES + j                          # worker i, prefix i + 1
+            pick = lane == j
+            p = jnp.sum(jnp.where(pick, p_chunk, 0.0), axis=1, keepdims=True)
+            w = jnp.sum(jnp.where(pick, w_chunk, 0), axis=1, keepdims=True)
+            keep = 1.0 - p
+            lo = jnp.maximum(i + floor_off, 0) // _LANES
+            hi = jnp.minimum(i + 1, top_max) // _LANES
+
+            def tile(u, carry):
+                # tiles from the highest down, so the tile below is still old
+                cur, rolled, acc = carry
+                t = hi - u
+                below = pmf_ref[t]
+                rolled_below = pltpu.roll(below, 1, 1)
+                shifted = jnp.where(lane == 0, rolled_below, rolled)
+                c = lane + t * _LANES
+                new = (cur * jnp.where(c < top, keep, 1.0)
+                       + shifted * jnp.where(c <= top, p, 0.0))
+                pmf_ref[t + 1] = new
+                acc = acc + jnp.where(c >= w, new, 0.0)
+                return below, rolled_below, acc
+
+            cur = pmf_ref[hi + 1]
+            _, _, acc = jax.lax.fori_loop(
+                0, jnp.maximum(hi - lo + 1, 0), tile,
+                (cur, pltpu.roll(cur, 1, 1), zeros))
+            tail = jnp.sum(acc, axis=1, keepdims=True)
+            tail = jnp.where(w > i + 1, 0.0, tail)      # infeasible prefix
+            return jnp.where(pick, tail, out_chunk)
+
+        steps = jnp.minimum(_LANES, n - k * _LANES)
+        out_ref[k] = jax.lax.fori_loop(0, steps, step, zeros)
+        return carry
+
+    jax.lax.fori_loop(0, probs_ref.shape[0], chunk, 0)
+
+
+def _wide_vmem_bytes(n: int, bb: int) -> int:
+    """VMEM the wide body asks for: its three (n_pad / 128, bb, 128) word
+    blocks and the top bins, double-buffered, the pmf, and 8 MiB to spare."""
+    tile = bb * _LANES * 4
+    blocks = 3 * (_round_up(n, _LANES) // _LANES) * tile + tile
+    return 2 * blocks + (n // _LANES + 2) * tile + (8 << 20)
+
+
+def _wide_caps(w: jnp.ndarray) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """(floor offset s, top bin t) of each row of (B, n) thresholds.
+
+    Prefix j + 1 scores only when feasible (w_j <= j + 1), and then reads
+    the counts >= v_j = max(w_j, 0).  A count c after worker i reaches at
+    most c + j - i after worker j, so it can still count only if c >= i +
+    min over feasible j >= i of (v_j - j); s, the minimum over all feasible
+    j, is a floor under that at every i.  t = max feasible v_j: every count
+    at or above it passes every feasible tail.  A row with no feasible
+    prefix gets (_NO_FLOOR, 0) and writes zeros.
+    """
+    j = jnp.arange(w.shape[1], dtype=jnp.int32)
+    feasible = w <= j + 1
+    v = jnp.maximum(w, 0)
+    floor_off = jnp.min(jnp.where(feasible, v - j, _NO_FLOOR), axis=1)
+    top = jnp.max(jnp.where(feasible, v, 0), axis=1)
+    return floor_off, top
+
+
+@functools.partial(jax.jit, static_argnames=("block_b", "interpret"))
+def success_tails_wide(
+    probs: jnp.ndarray,
+    w: jnp.ndarray,
+    *,
+    block_b: int = _WIDE_BB,
+    interpret: bool = False,
+) -> jnp.ndarray:
+    """(B, n) probabilities + (B, n) traced int32 thresholds -> (B, n) tails.
+
+    The wide body: the worker loop is a ``fori_loop`` and the count axis is
+    capped per row (:func:`_wide_caps`) and worked in 128-lane tiles, so
+    neither the compile nor VMEM grows with n beyond the (n_pad / 128, bb,
+    128) blocks.  Traced under the ``repro.dp_wide`` device scope.
+    """
+    b, n = probs.shape
+    assert w.shape == (b, n), (w.shape, (b, n))
+    with _phase("dp_wide"):
+        bb = min(block_b, _round_up(b, 8))
+        b_pad = _round_up(b, bb)
+        n_pad = _round_up(n, _LANES)
+        chunks = n_pad // _LANES
+        w = w.astype(jnp.int32)
+        floor_off, top = _wide_caps(w)
+        floor_off = jnp.pad(floor_off, (0, b_pad - b), constant_values=_NO_FLOOR)
+        top = jnp.pad(top, (0, b_pad - b))
+        bounds = jnp.stack([jnp.min(floor_off.reshape(-1, bb), axis=1),
+                            jnp.max(top.reshape(-1, bb), axis=1)], axis=1)[:, None]
+
+        def by_chunk(x, fill):
+            x = jnp.pad(x, ((0, b_pad - b), (0, n_pad - n)), constant_values=fill)
+            return x.reshape(b_pad, chunks, _LANES).transpose(1, 0, 2)
+
+        out = pl.pallas_call(
+            functools.partial(_pb_wide_kernel, n=n),
+            grid=(b_pad // bb,),
+            in_specs=[
+                pl.BlockSpec((None, 1, 2), lambda i: (i, 0, 0),
+                             memory_space=pltpu.SMEM),
+                pl.BlockSpec((chunks, bb, _LANES), lambda i: (0, i, 0)),
+                pl.BlockSpec((chunks, bb, _LANES), lambda i: (0, i, 0)),
+                pl.BlockSpec((bb, _LANES), lambda i: (i, 0)),
+            ],
+            out_specs=pl.BlockSpec((chunks, bb, _LANES), lambda i: (0, i, 0)),
+            out_shape=jax.ShapeDtypeStruct((chunks, b_pad, _LANES), jnp.float32),
+            scratch_shapes=[pltpu.VMEM((n // _LANES + 2, bb, _LANES), jnp.float32)],
+            compiler_params=pltpu.CompilerParams(
+                vmem_limit_bytes=_wide_vmem_bytes(n, bb)),
+            interpret=interpret,
+        )(bounds, by_chunk(probs.astype(jnp.float32), 0.0), by_chunk(w, n + 1),
+          jnp.broadcast_to(top[:, None], (b_pad, _LANES)))
+        return out.transpose(1, 0, 2).reshape(b_pad, n_pad)[:b, :n]
 
 
 def _round_up(v: int, m: int) -> int:

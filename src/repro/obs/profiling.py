@@ -52,6 +52,8 @@ DEVICE_SCOPES = (
     "release",        # serving/engine.py: slot recycling
     "admission_dp",   # serving/engine.py: batched admission prediction
     "gf_matvec",      # kernels/gf/kernel.py: the GF(p) narrow-width body
+    "dp_wide",        # kernels/poisson_binomial/kernel.py, inside allocate:
+                      # the allocator DP's wide body
 )
 
 # every host span the program opens (through annotate, or timed)

@@ -25,6 +25,9 @@ Computing* (arXiv:2206.09399)):
                           to the widest ramp point, again ONE compile
   * ``straggler_slack`` — speed-ratio x deadline grid: how much straggler
                           slack LEA can squeeze vs static
+  * ``fleet4k``         — the Sec. 6.2 t2.micro worker at the width of a
+                          production cluster (4,000 workers, the paper's
+                          five chains mixed), K* at Sec. 6.2's two ratios
 
 Non-stationary families (the ``repro.policies`` proving grounds — chains
 whose parameters move, where windowed/discounted estimators beat vanilla
@@ -286,6 +289,47 @@ def elastic_pool(
             p_gg=_const(n, p_gg), p_bb=_const(n, p_bb),
             mu_g=SIM.mu_g, mu_b=SIM.mu_b, deadline=SIM.deadline, rounds=rounds,
             meta=(("n", n), ("kstar", spec.recovery_threshold)),
+        ))
+    return tuple(scenarios)
+
+
+# The paper's five published chains: Sec. 6.1's four and Sec. 6.2's
+# credit-based t2.micro chain; stationary good shares 0.5 to 0.8.
+FLEET_CHAINS = SIM.scenarios + ((FIG4_P_GG, FIG4_P_BB),)
+
+
+@register("fleet4k")
+def fleet4k(
+    n: int = 4_000,
+    ks: tuple[int, ...] = (50, 100),
+    rounds: int = 2_000,
+) -> tuple[Scenario, ...]:
+    """The Sec. 6.2 t2.micro worker (mu_g = 10, mu_b = 1, r = 10, d = 1, deg
+    f = 1) at fleet width: n = 4,000 is the machine count of Alibaba's
+    cluster-trace-v2018, rounded.  Worker i runs chain i mod 5 of
+    :data:`FLEET_CHAINS`, one request a round on the per-round chain (no
+    arrival-gap folding).  One scenario per Sec. 6.2 job size k of its n r
+    = 150 chunks, K* scaled to the same share of this fleet's n r: k = 50
+    and 100 give K* = 13,334 (comfortably feasible) and 26,667 (the fleet's
+    mean capacity when every worker takes ell_g, where LEA's argmax decides
+    the outcome).  LEA against the EC2 static draw."""
+    ell_g = int(min(EC2.mu_g, EC2.r))
+    ell_b = int(EC2.mu_b)
+    chains = [FLEET_CHAINS[i % len(FLEET_CHAINS)] for i in range(n)]
+    p_gg = tuple(float(c[0]) for c in chains)
+    p_bb = tuple(float(c[1]) for c in chains)
+    scenarios = []
+    for i, k in enumerate(ks, 1):
+        data = -(-k * n * EC2.r // (EC2.n * EC2.r))   # ceil(k / 150 * n r)
+        spec = CodeSpec(n, EC2.r, data, EC2.deg_f)
+        lp = LoadParams(n=n, kstar=spec.recovery_threshold,
+                        ell_g=ell_g, ell_b=ell_b)
+        scenarios.append(Scenario(
+            name=f"fleet4k_kstar{lp.kstar}", family="fleet4k", lp=lp,
+            p_gg=p_gg, p_bb=p_bb, mu_g=float(EC2.mu_g), mu_b=float(EC2.mu_b),
+            deadline=1.0, rounds=rounds,
+            strategies=("lea", "static_single"), baseline="static_single",
+            seed=i, meta=(("n", n), ("k_of_150", k), ("kstar", lp.kstar)),
         ))
     return tuple(scenarios)
 

@@ -57,6 +57,15 @@ def test_prefix_thresholds_traced_matches_numpy(n, seed):
     np.testing.assert_array_equal(np.asarray(got), want)
 
 
+@pytest.mark.parametrize("kstar", [13_334, 26_667, 39_999])
+def test_prefix_thresholds_traced_exact_at_fleet_width(kstar):
+    """The four-chip fleet's thresholds (n = 4,000, ell_g = 10, ell_b = 1):
+    the int32 ceil-div equals the float64 ceil at every prefix."""
+    lp = LoadParams(n=4000, kstar=kstar, ell_g=10, ell_b=1)
+    got = lea.prefix_thresholds_traced(kstar, 10, 1, 4000, 4000)
+    np.testing.assert_array_equal(np.asarray(got), lea.prefix_thresholds(lp))
+
+
 def test_prefix_thresholds_traced_pads_infeasible_past_valid_pool():
     got = np.asarray(lea.prefix_thresholds_traced(
         jnp.asarray(9), jnp.asarray(4), jnp.asarray(1), jnp.asarray(3), 6
@@ -112,6 +121,26 @@ def test_allocate_masked_full_width_bit_identical_to_allocate(n, b, seed):
     np.testing.assert_array_equal(np.asarray(loads_s), np.asarray(loads_m))
     np.testing.assert_array_equal(np.asarray(istar_s), np.asarray(istar_m))
     assert bool(jnp.all(feasible))          # _random_lp keeps kstar <= n*ell_g
+
+
+@pytest.mark.parametrize("n", [lea._PAIRWISE_RANK_MAX_N + 8, 300])
+def test_allocate_masked_wide_loads_split_at_the_stable_rank(n):
+    """Past the pairwise cut the argsort branch ranks the pool: the i*
+    workers first in the stable descending order of p (ties by worker
+    index) get ell_g."""
+    rng = np.random.default_rng(n)
+    b = 24
+    p = rng.choice([0.2, 0.5, 0.5, 0.7, 0.9], size=(b, n)).astype(np.float32)
+    mask = rng.uniform(size=(b, n)) < 0.9
+    pool = PoolLoad(kstar=jnp.asarray(rng.integers(n, 8 * n, size=b), jnp.int32),
+                    ell_g=jnp.full((b,), 10, jnp.int32),
+                    ell_b=jnp.full((b,), 1, jnp.int32), mask=jnp.asarray(mask))
+    loads, i_star, _ = lea.allocate_masked(jnp.asarray(p), pool)
+    p_eff = np.where(mask, p, np.float32(-1.0))
+    ranks = np.argsort(np.argsort(-p_eff, axis=-1, kind="stable"), axis=-1,
+                       kind="stable")
+    want = np.where(mask, np.where(ranks < np.asarray(i_star)[:, None], 10, 1), 0)
+    np.testing.assert_array_equal(np.asarray(loads), want)
 
 
 @settings(max_examples=20, deadline=None)
@@ -310,6 +339,20 @@ def test_sample_trajectory_mask_freezes_masked_workers():
     # scan reference agrees under the mask too
     scan = markov.sample_trajectory_scan(key, p_gg, p_bb, 200, worker_mask=mask)
     np.testing.assert_array_equal(np.asarray(traj), np.asarray(scan))
+
+
+@pytest.mark.parametrize("n", [markov.SEQUENTIAL_N - 1, markov.SEQUENTIAL_N])
+def test_sample_trajectory_either_side_of_the_sequential_cut(n):
+    """Below the cut the associative scan, from it on the stepped sampler:
+    both the stepped sampler's bits, masked workers frozen."""
+    key = jax.random.PRNGKey(n)
+    p_gg = jnp.linspace(0.5, 0.95, n)
+    p_bb = jnp.linspace(0.9, 0.4, n)
+    mask = jnp.arange(n) < n - 3
+    traj = markov.sample_trajectory(key, p_gg, p_bb, 64, worker_mask=mask)
+    scan = markov.sample_trajectory_scan(key, p_gg, p_bb, 64, worker_mask=mask)
+    np.testing.assert_array_equal(np.asarray(traj), np.asarray(scan))
+    assert bool(jnp.all(traj[:, n - 3:] == 1))
 
 
 def test_frozen_pad_chain_is_deterministically_good():

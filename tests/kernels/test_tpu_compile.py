@@ -10,6 +10,7 @@ this file.
 """
 
 import os
+import time
 
 import jax
 import jax.numpy as jnp
@@ -21,10 +22,15 @@ from repro.core.lagrange import CodeSpec
 from repro.core.lea import LoadParams
 from repro.kernels.gf import bmm_gf
 from repro.kernels.gf.kernel import matmul_gf_pallas
+from repro.kernels.poisson_binomial import WIDE_N
 from repro.kernels.poisson_binomial.kernel import (success_tails_pallas,
-                                                   success_tails_pallas_w)
+                                                   success_tails_pallas_w,
+                                                   success_tails_wide)
 
 ENGINE_BATCH = 4096                      # rows of one engine allocate call
+# a chip's share of the four-chip fleet sweep: 32 sweep rows, each one DP row
+# a round of a 250-round block, vmapped as the engine calls the DP
+FLEET_ROWS, FLEET_CHUNK = 32, 250
 FIG3 = LoadParams(n=15, kstar=99, ell_g=10, ell_b=3)
 EC2 = CodeSpec(15, 10, 120, deg_f=1)     # K* = 120
 EC2_ROWS, EC2_COLS = 25, 3000
@@ -75,6 +81,28 @@ def test_poisson_binomial_traced_w_compiles(one_chip):
                              sharding=one_chip)
     hlo = _compile(success_tails_pallas_w, p, w)
     assert "tpu_custom_call" in hlo
+
+
+def _compile_wide(one_chip, n):
+    p = jax.ShapeDtypeStruct((FLEET_ROWS, FLEET_CHUNK, n), jnp.float32,
+                             sharding=one_chip)
+    w = jax.ShapeDtypeStruct((FLEET_ROWS, FLEET_CHUNK, n), jnp.int32,
+                             sharding=one_chip)
+    t0 = time.perf_counter()
+    hlo = _compile(jax.vmap(success_tails_wide), p, w)
+    return hlo, time.perf_counter() - t0
+
+
+def test_poisson_binomial_wide_compiles_at_fleet_width(one_chip):
+    """The wide body at the cut and at 4,000 workers: the worker loop is
+    rolled, so the compile does not grow with n (the unrolled body took 40 s
+    and ran out of VMEM at n = 128)."""
+    at_cut, t_cut = _compile_wide(one_chip, WIDE_N)
+    at_fleet, t_fleet = _compile_wide(one_chip, 4000)
+    for hlo in (at_cut, at_fleet):
+        assert "tpu_custom_call" in hlo
+        assert "repro.dp_wide" in hlo
+    assert t_fleet < 3 * t_cut + 5.0, (t_cut, t_fleet)
 
 
 @pytest.mark.parametrize("stage", ["encode", "worker_products", "decode"])

@@ -4,6 +4,7 @@ no ``repro.*`` name hides inside another (trace readers match by substring).
 """
 
 import itertools
+import os
 import pathlib
 import re
 
@@ -16,7 +17,9 @@ from repro import serving, sweeps
 from repro.core.coded_ops import (_decode_on_time_modp, _products_modp,
                                   coded_matmul_exact, encode_dataset_modp)
 from repro.core.lagrange import CodeSpec
+from repro.kernels import dispatch
 from repro.kernels.gf.kernel import matmul_gf_pallas
+from repro.kernels.poisson_binomial import WIDE_N
 from repro.obs.profiling import DEVICE_SCOPES, HOST_SPANS, phase
 from repro.serving.engine import _run_serving_group
 from repro.sweeps import executor
@@ -94,6 +97,30 @@ def _gf_matvec_hlo() -> str:
     return matmul_gf_pallas.lower(a, b, interpret=True).as_text(debug_info=True)
 
 
+def _wide_sweep_group_hlo() -> str:
+    """A sweep group at the allocator DP's cut, with the kernels the chip
+    runs (interpreted): compiled, so the wide body's scope shows nested in
+    the allocation."""
+    forced = {dispatch.ENV_IMPL: "pallas", dispatch.ENV_INTERPRET: "1"}
+    was = {k: os.environ.get(k) for k in forced}
+    os.environ.update(forced)
+    try:
+        scenarios = sweeps.expand("fleet4k", n=WIDE_N, rounds=4)
+        (group,) = sweeps.build_groups(scenarios, seeds=1)
+        b = group.batch
+        return executor._run_group.lower(
+            b.keys, b.p_gg, b.p_bb, b.mu_g, b.mu_b, b.deadline, b.pool,
+            rounds=group.rounds, strategies=group.strategies, round_chunk=None,
+            telemetry=False, tap=False, tap_stride=None, mesh=None,
+        ).compile().as_text()
+    finally:
+        for k, v in was.items():
+            if v is None:
+                os.environ.pop(k)
+            else:
+                os.environ[k] = v
+
+
 PROGRAMS = {
     "sweep_group": (_sweep_group_hlo,
                     ("trajectory", "policy_replay", "allocate", "score")),
@@ -102,6 +129,7 @@ PROGRAMS = {
                        "score", "release")),
     "coded_round": (_coded_round_hlo, ("products", "decode")),
     "gf_matvec": (_gf_matvec_hlo, ("gf_matvec",)),
+    "wide_sweep_group": (_wide_sweep_group_hlo, ("allocate", "dp_wide")),
 }
 
 
@@ -118,6 +146,8 @@ def test_scopes_reach_the_lowered_hlo(program):
         assert f"repro.{scope}" in hlo, (program, scope)
     if "edf_order" in scopes:            # counted inside the allocation too
         assert "repro.allocate/repro.edf_order" in hlo
+    if "dp_wide" in scopes:
+        assert re.search(r"repro\.allocate/[^\"]*repro\.dp_wide/", hlo)
 
 
 # -- host spans in a profiler trace ---------------------------------------------
